@@ -28,6 +28,9 @@ def main() -> None:
 
     import functools
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
+
     from benchmarks import (amtl_events, fig3_scaling, fig4_convergence,
                             kernels_bench, serving, sgd_amtl, table1_timing,
                             table3_public, table456_dynamic_step)

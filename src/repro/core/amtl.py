@@ -139,9 +139,14 @@ from repro.core.operators import (amtl_max_step, backward,
                                   rollback_columns_shard)
 from repro.core.prox import ProxPlan, svt_randomized, svt_randomized_dist
 from repro.distributed.sharding import (TASK_AXIS, prox_cache_spec,
-                                        shard_map_compat, task_shard_specs)
+                                        task_shard_specs)
 
 Array = jax.Array
+# The engines compute in f32: their matmuls (task gradients, the prox's
+# sketch core and reconstruction) run at f32 precision on every backend.
+# XLA's TPU default is one bf16 pass, which on a v5e left the distributed
+# and replicated prox 1.4e-3 apart (relative) after 320 events.
+F32_MATMUL = "highest"
 
 
 class AMTLConfig(NamedTuple):
@@ -793,11 +798,11 @@ def _one_batch_sharded(problem: MTLProblem, cfg: AMTLConfig,
                                    problem.reg_name, problem.lam)
             return local_body(problem_l, offs, st)
 
-        step = shard_map_compat(
+        step = jax.shard_map(
             local_step, mesh=mesh,
             in_specs=(sp["per_task"], sp["per_task"], sp["replicated"],
                       state_specs),
-            out_specs=state_specs)
+            out_specs=state_specs, check_vma=False)
         return step(problem.xs, problem.ys, delay_offsets, state)
 
     # Ragged: row_counts ride along as one more per_task input — each
@@ -808,11 +813,11 @@ def _one_batch_sharded(problem: MTLProblem, cfg: AMTLConfig,
                                problem.reg_name, problem.lam, rcs)
         return local_body(problem_l, offs, st)
 
-    step = shard_map_compat(
+    step = jax.shard_map(
         local_step_ragged, mesh=mesh,
         in_specs=(sp["per_task"], sp["per_task"], sp["per_task"],
                   sp["replicated"], state_specs),
-        out_specs=state_specs)
+        out_specs=state_specs, check_vma=False)
     return step(problem.xs, problem.ys, problem.row_counts, delay_offsets,
                 state)
 
@@ -923,16 +928,19 @@ def _run_events(problem: MTLProblem, cfg: AMTLConfig, state,
     """
     step = _step_fn(cfg, mesh)
     per_step = cfg.event_batch if cfg.engine in ("batch", "sharded") else 1
-    return jax.lax.fori_loop(
-        0, num_events // per_step,
-        lambda _, s: step(problem, cfg, delay_offsets, s), state)
+    with jax.default_matmul_precision(F32_MATMUL):
+        return jax.lax.fori_loop(
+            0, num_events // per_step,
+            lambda _, s: step(problem, cfg, delay_offsets, s), state)
 
 
 @functools.partial(jax.jit, static_argnames=("cfg",))
 def _iterate_metrics(problem: MTLProblem, cfg: AMTLConfig, v: Array):
     """(W, objective, BF residual) of the current iterate V."""
-    w = backward(problem, v, cfg.eta)
-    return w, problem.objective(w), fixed_point_residual(problem, v, cfg.eta)
+    with jax.default_matmul_precision(F32_MATMUL):
+        w = backward(problem, v, cfg.eta)
+        return (w, problem.objective(w),
+                fixed_point_residual(problem, v, cfg.eta))
 
 
 class AMTLEngine(NamedTuple):

@@ -17,7 +17,6 @@ the 512-chip dry run.
 """
 from __future__ import annotations
 
-import inspect
 import math
 from typing import Any, Optional
 
@@ -28,25 +27,6 @@ from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ArchConfig
 
-
-def shard_map_compat(f, *, mesh, in_specs, out_specs, check_vma=False):
-    """`jax.shard_map` across jax versions.
-
-    Newer jax exposes top-level `jax.shard_map`; 0.4.x only has
-    `jax.experimental.shard_map.shard_map`.  The replication-check kwarg
-    was also renamed `check_rep` -> `check_vma` (same switch), not
-    necessarily in the same release — so detect the accepted kwarg from
-    the signature rather than guessing from the module layout.  All
-    shard_map call sites in this repo go through here.
-    """
-    if hasattr(jax, "shard_map"):
-        sm = jax.shard_map
-    else:
-        from jax.experimental.shard_map import shard_map as sm
-    params = inspect.signature(sm).parameters
-    kw = "check_vma" if "check_vma" in params else "check_rep"
-    return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-              **{kw: check_vma})
 
 # ------------------------------------------------------- AMTL task axis ---
 

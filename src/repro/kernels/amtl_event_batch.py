@@ -39,6 +39,7 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.kernels.ref import last_occurrence_mask
 
 Array = jax.Array
+HIGHEST = jax.lax.Precision.HIGHEST
 
 BLOCK_ROWS = 256   # sublane-multiple tile rows over d
 LANES = 128
@@ -59,7 +60,10 @@ def _make_kernel(batch: int):
         # carry task -1 and match nothing.
         col_of = jax.lax.broadcasted_iota(jnp.int32, (tp, bp), 0)
         onehot = (col_of == tv).astype(jnp.float32)
-        cols = jnp.dot(v, onehot, preferred_element_type=jnp.float32)
+        # f32 contract precision keeps the one-hot gather and scatter
+        # exact; the MXU's default single bf16 pass rounds V to bf16.
+        cols = jnp.dot(v, onehot, preferred_element_type=jnp.float32,
+                       precision=HIGHEST)
 
         # fuse: serialize the B events; each output is forwarded to later
         # duplicate events so their read sees the in-batch write.
@@ -82,7 +86,8 @@ def _make_kernel(batch: int):
         # (Bp, 1) column so no in-kernel transpose is needed.
         scat = (row_ev == last_v[...]).astype(jnp.float32)      # (Bp, Tp)
         covered = jnp.sum(scat, axis=0, keepdims=True)          # (1, Tp)
-        placed = jnp.dot(outs, scat, preferred_element_type=jnp.float32)
+        placed = jnp.dot(outs, scat, preferred_element_type=jnp.float32,
+                         precision=HIGHEST)
         vnew = jnp.where(covered > 0, placed, v)
         vnew_ref[...] = vnew.astype(vnew_ref.dtype)
         undo_ref[...] = undos.astype(undo_ref.dtype)

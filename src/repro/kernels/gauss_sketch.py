@@ -28,6 +28,9 @@ from jax.experimental import pallas as pl
 from repro.kernels.ref import gauss_from_counters
 
 Array = jax.Array
+# f32 contractions: on the chip the MXU default is one bf16 pass, which
+# puts a ~1e-3 relative error on an f32 problem.
+HIGHEST = jax.lax.Precision.HIGHEST
 
 BLOCK_D = 1024
 BLOCK_T = 128
@@ -47,7 +50,8 @@ def _sketch_kernel(seed_ref, off_ref, w_ref, out_ref, *, bt: int, p: int,
             + row0)
     cols = jax.lax.broadcasted_iota(jnp.uint32, (bt, pp), 1)
     omega = gauss_from_counters(seed_ref[0, 0], rows * jnp.uint32(p) + cols)
-    contrib = jnp.dot(w, omega, preferred_element_type=jnp.float32)
+    contrib = jnp.dot(w, omega, preferred_element_type=jnp.float32,
+                      precision=HIGHEST)
 
     @pl.when(j == 0)
     def _init():

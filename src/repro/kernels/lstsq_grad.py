@@ -20,10 +20,22 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 Array = jax.Array
+# f32 contractions: on the chip the MXU default is one bf16 pass, which
+# puts a ~1e-3 relative error on an f32 problem.
+HIGHEST = jax.lax.Precision.HIGHEST
 
 BLOCK_N = 512
+# VMEM budget.  Pallas double-buffers each (block_n, d) strip of X, the
+# f32-precision X^T r contraction holds its transpose and bf16 splits of
+# it, and the (d, 1) w and output blocks are lane-padded to (d, 128).  A
+# strip capped at X_STRIP_BYTES and a scoped VMEM limit of VMEM_LIMIT
+# (the default is 16 MiB; a v5e core has 128 MiB) keep d up to 16384
+# compiling; 512-row strips at d=4096 did not.
+X_STRIP_BYTES = 2 << 20
+VMEM_LIMIT = 64 << 20
 
 
 def _lstsq_kernel(x_ref, w_ref, y_ref, out_ref):
@@ -31,8 +43,10 @@ def _lstsq_kernel(x_ref, w_ref, y_ref, out_ref):
     x = x_ref[...].astype(jnp.float32)          # (bn, d)
     w = w_ref[...].astype(jnp.float32)          # (d, 1)
     y = y_ref[...].astype(jnp.float32)          # (bn, 1)
-    r = jnp.dot(x, w, preferred_element_type=jnp.float32) - y
-    contrib = 2.0 * jnp.dot(x.T, r, preferred_element_type=jnp.float32)
+    r = jnp.dot(x, w, preferred_element_type=jnp.float32,
+                precision=HIGHEST) - y
+    contrib = 2.0 * jnp.dot(x.T, r, preferred_element_type=jnp.float32,
+                            precision=HIGHEST)
 
     @pl.when(i == 0)
     def _init():
@@ -56,11 +70,13 @@ def _lstsq_kernel_masked(scal_ref, x_ref, w_ref, y_ref, out_ref, *, bn: int):
     x = x_ref[...].astype(jnp.float32)          # (bn, d)
     w = w_ref[...].astype(jnp.float32)          # (d, 1)
     y = y_ref[...].astype(jnp.float32)          # (bn, 1)
-    r = jnp.dot(x, w, preferred_element_type=jnp.float32) - y
+    r = jnp.dot(x, w, preferred_element_type=jnp.float32,
+                precision=HIGHEST) - y
     rows = (jax.lax.broadcasted_iota(jnp.int32, (bn, 1), 0)
             + i * bn).astype(jnp.uint32)
     r = jnp.where(rows < n_t, r, 0.0)
-    contrib = 2.0 * jnp.dot(x.T, r, preferred_element_type=jnp.float32)
+    contrib = 2.0 * jnp.dot(x.T, r, preferred_element_type=jnp.float32,
+                            precision=HIGHEST)
 
     @pl.when(i == 0)
     def _init():
@@ -84,7 +100,7 @@ def lstsq_grad(x: Array, w: Array, y: Array, *, n_t: Array | None = None,
     """
     n, d = x.shape
     pd = _round_up(d, 128)
-    bn = min(block_n, _round_up(n, 128))
+    bn = strip_rows(n, pd, block_n)
     pn = _round_up(n, bn)
     # Zero padding is exact: padded rows contribute X_pad @ w - 0 = 0 rows
     # only when X_pad = 0 AND y_pad = 0 => r_pad = 0 => no gradient effect.
@@ -110,9 +126,17 @@ def lstsq_grad(x: Array, w: Array, y: Array, *, n_t: Array | None = None,
             pl.BlockSpec((bn, 1), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((pd, 1), lambda i: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((pd, 1), w.dtype),
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=VMEM_LIMIT),
         interpret=interpret,
     )(*args, x_p, w_p, y_p)
     return out[:d, 0]
+
+
+def strip_rows(n: int, pd: int, block_n: int) -> int:
+    """Rows per strip of an (n, pd) X: `block_n`, cut to a multiple of 8
+    that keeps one f32 strip within `X_STRIP_BYTES`."""
+    fit = max(8, X_STRIP_BYTES // (4 * pd) // 8 * 8)
+    return min(block_n, fit, _round_up(n, 128))
 
 
 def _round_up(x: int, m: int) -> int:

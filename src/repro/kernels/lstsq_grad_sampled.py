@@ -32,10 +32,15 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.lstsq_grad import VMEM_LIMIT, strip_rows
 from repro.kernels.ref import counter_hash, sample_cutoff, sample_cutoff_masked
 
 Array = jax.Array
+# f32 contractions: on the chip the MXU default is one bf16 pass, which
+# puts a ~1e-3 relative error on an f32 problem.
+HIGHEST = jax.lax.Precision.HIGHEST
 
 BLOCK_N = 512
 LANES = 128
@@ -68,17 +73,20 @@ def _sampled_kernel(scal_ref, x_ref, w_ref, y_ref, out_ref, *, bn: int,
     x = x_ref[...].astype(jnp.float32)          # (bn, d)
     w = w_ref[...].astype(jnp.float32)          # (d, 1)
     y = y_ref[...].astype(jnp.float32)          # (bn, 1)
-    r = jnp.dot(x, w, preferred_element_type=jnp.float32) - y
+    r = jnp.dot(x, w, preferred_element_type=jnp.float32,
+                precision=HIGHEST) - y
     keep = _keep_bits(scal_ref, i * bn, bn)
     r = jnp.where(keep, r, 0.0)
     # (n_t/bsz) unbiased scale from the scalar block: integer operands are
     # < 2^24, so this f32 division carries the exact bits of the former
-    # trace-time Python-float constant (x2 is exact in binary fp).
-    n_t = scal_ref[0, 3]
-    bsz = jnp.minimum(jnp.uint32(batch_size), n_t)
+    # trace-time Python-float constant (x2 is exact in binary fp).  They
+    # go through int32 (exact below 2^24): Mosaic has no uint32 -> f32.
+    n_t = scal_ref[0, 3].astype(jnp.int32)
+    bsz = jnp.minimum(jnp.int32(batch_size), n_t)
     scale2 = 2.0 * (n_t.astype(jnp.float32)
-                    / jnp.maximum(bsz, jnp.uint32(1)).astype(jnp.float32))
-    contrib = scale2 * jnp.dot(x.T, r, preferred_element_type=jnp.float32)
+                    / jnp.maximum(bsz, 1).astype(jnp.float32))
+    contrib = scale2 * jnp.dot(x.T, r, preferred_element_type=jnp.float32,
+                               precision=HIGHEST)
 
     @pl.when(i == 0)
     def _init():
@@ -119,7 +127,7 @@ def lstsq_grad_sampled(x: Array, w: Array, y: Array, seed: Array, *,
     """
     n, d = x.shape
     pd = _round_up(d, 128)
-    bn = min(block_n, _round_up(n, 128))
+    bn = strip_rows(n, pd, block_n)
     pn = _round_up(n, bn)
     # Zero padding stays exact under sampling: a padded row's keep bit is
     # dropped by the row < n_t conjunct, and even without it X_pad = 0 AND
@@ -137,6 +145,7 @@ def lstsq_grad_sampled(x: Array, w: Array, y: Array, seed: Array, *,
                   pl.BlockSpec((bn, 1), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((pd, 1), lambda i: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((pd, 1), w.dtype),
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=VMEM_LIMIT),
         interpret=interpret,
     )(_scalars(n, batch_size, seed, n_t), x_p, w_p, y_p)
     return out[:d, 0]

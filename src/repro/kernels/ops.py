@@ -1,8 +1,13 @@
 """Jit'd public wrappers around the Pallas kernels.
 
-Dispatch policy: Pallas kernels target TPU.  On CPU (this container, and the
-512-fake-device dry-run) the pure-jnp oracle path is used unless
-`interpret=True` is requested (tests validate kernels in interpret mode).
+Dispatch policy: on a TPU (`jax.default_backend() == "tpu"`) every wrapper
+runs its Pallas kernel.  On the CPU the pure-jnp oracle in `ref.py` runs
+instead; that path exists for the tests and the fake-device dry runs, not
+for deployment.  `interpret=True` runs a kernel in Pallas interpret mode
+on the CPU, which is how the tests check kernels against the oracles.
+`chip_smoke.py` asserts on the chip that each main-path wrapper compiles
+to its Pallas kernel (`tpu_custom_call`), and tests/test_tpu_compile.py
+compiles them for a described v5e without one.
 """
 from __future__ import annotations
 

@@ -346,8 +346,11 @@ def gauss_from_counters(seed: Array, ctr: Array) -> Array:
     """
     u1 = counter_hash(seed, ctr * jnp.uint32(2))
     u2 = counter_hash(seed, ctr * jnp.uint32(2) + jnp.uint32(1))
-    f1 = ((u1 >> 8).astype(jnp.float32) + 1.0) * jnp.float32(2.0 ** -24)
-    f2 = (u2 >> 8).astype(jnp.float32) * jnp.float32(2.0 ** -24)
+    # Mosaic has no uint32 -> f32 cast; 24-bit values fit int32 exactly.
+    f1 = ((u1 >> 8).astype(jnp.int32).astype(jnp.float32) + 1.0) \
+        * jnp.float32(2.0 ** -24)
+    f2 = (u2 >> 8).astype(jnp.int32).astype(jnp.float32) \
+        * jnp.float32(2.0 ** -24)
     return jnp.sqrt(-2.0 * jnp.log(f1)) * jnp.cos(
         jnp.float32(2.0 * 3.141592653589793) * f2)
 

@@ -26,6 +26,9 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 Array = jax.Array
+# f32 contractions: on the chip the MXU default is one bf16 pass, which
+# puts a ~1e-3 relative error on an f32 problem.
+HIGHEST = jax.lax.Precision.HIGHEST
 
 BLOCK_ROWS = 256   # sublane-multiple tile rows over d
 LANES = 128
@@ -35,7 +38,8 @@ def _kernel(qu_ref, s_ref, vt_ref, out_ref):
     qu = qu_ref[...].astype(jnp.float32)           # (br, pp)
     s = s_ref[...].astype(jnp.float32)             # (1, pp) lane row
     vt = vt_ref[...].astype(jnp.float32)           # (pp, mp)
-    out = jnp.dot(qu * s, vt, preferred_element_type=jnp.float32)
+    out = jnp.dot(qu * s, vt, preferred_element_type=jnp.float32,
+                  precision=HIGHEST)
     out_ref[...] = out.astype(out_ref.dtype)
 
 

@@ -15,7 +15,6 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ArchConfig
-from repro.distributed.sharding import shard_map_compat
 from repro.models.layers import apply_norm, apply_rope, dense_init, init_norm
 
 Array = jax.Array
@@ -378,7 +377,7 @@ def attn_decode_sharded(p: dict, x: Array, cache: KVCache, pos: Array,
     if quant:
         rep3 = P(bspec, None, None)
         sspec = P(bspec, maxis, None)
-        out, k, v, ks, vs = shard_map_compat(
+        out, k, v, ks, vs = jax.shard_map(
             kernel_q, mesh=ctx.mesh,
             in_specs=(rep, rep, rep, rep3, rep3, cspec, cspec, sspec,
                       sspec, P(), P()),
@@ -389,7 +388,7 @@ def attn_decode_sharded(p: dict, x: Array, cache: KVCache, pos: Array,
         out = out.reshape(b, 1, -1) @ p["wo"].astype(x.dtype)
         return out, KVCache(k=k, v=v, k_scale=ks, v_scale=vs)
 
-    out, k, v = shard_map_compat(
+    out, k, v = jax.shard_map(
         kernel, mesh=ctx.mesh,
         in_specs=(rep, rep, rep, cspec, cspec, P(), P()),
         out_specs=(rep, cspec, cspec), check_vma=False)(
@@ -659,7 +658,7 @@ def mla_decode_sharded(p: dict, x: Array, cache: MLACache, pos: Array,
 
     q4 = P(bspec, None, None, None)
     c3 = P(bspec, maxis, None)
-    out_lat, c, kr = shard_map_compat(
+    out_lat, c, kr = jax.shard_map(
         kernel, mesh=ctx.mesh,
         in_specs=(q4, q4, P(bspec, None, None), P(bspec, None, None),
                   c3, c3, P(), P()),

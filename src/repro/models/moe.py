@@ -36,7 +36,6 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ArchConfig
-from repro.distributed.sharding import shard_map_compat
 from repro.models.layers import activate, dense_init, is_gated
 
 Array = jax.Array
@@ -330,7 +329,7 @@ def moe_ep(p: dict, x: Array, cfg: ArchConfig, ctx: ParallelCtx,
         aux = jax.lax.pmean(aux, tuple(mesh.axis_names))
         return y.reshape(bl, sl, d), aux
 
-    y, aux = shard_map_compat(kernel, mesh=mesh, in_specs=tuple(in_specs),
+    y, aux = jax.shard_map(kernel, mesh=mesh, in_specs=tuple(in_specs),
                            out_specs=(token_spec, P()), check_vma=False)(*args)
     return y, aux
 
