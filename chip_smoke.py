@@ -53,7 +53,8 @@ sys.path.insert(0, str(Path(__file__).resolve().parent / "src"))
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 
-# Engine/machinery shape (the amtl_events bench's).
+# Engine/machinery shape: a small problem where the engine's own work
+# dominates.
 D, T, N, TAU, EVENT_BATCH, PROX_RANK = 8192, 128, 4, 8, 32, 16
 # Serve shape: ragged store of up to N_S rows per task, SGD minibatches.
 D_S, T_S, N_S, BATCH_SIZE = 4096, 128, 512, 32

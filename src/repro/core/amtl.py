@@ -448,16 +448,14 @@ def _one_event_delta(problem: MTLProblem, cfg: AMTLConfig,
 
     depth = cfg.tau + 1
     use_randomized = cfg.prox_rank is not None and problem.reg_name == "nuclear"
-    key, t, nu = _sample_activation(cfg, delay_offsets, state.key,
-                                    problem.num_tasks, state.event)
-    # The sketch key is folded off the pre-event key instead of split from
-    # the main chain, so the task/staleness event stream stays identical to
-    # the dense engine even when the randomized refresh is enabled.  The
-    # minibatch sampling seed follows the same pattern at a different fold
-    # constant.
-    k_prox = jax.random.fold_in(state.key, 7) if use_randomized else None
-    mb_seed = _minibatch_seed(state.key) if cfg.batch_size is not None \
-        else None
+    with jax.named_scope("amtl.sample"):
+        key, t, nu = _sample_activation(cfg, delay_offsets, state.key,
+                                        problem.num_tasks, state.event)
+        # The minibatch sampling seed is folded off the pre-event key
+        # instead of split from the main chain, so the task/staleness
+        # event stream stays identical to the dense engine.
+        mb_seed = _minibatch_seed(state.key) if cfg.batch_size is not None \
+            else None
     v = state.v
 
     def refresh(_):
@@ -473,38 +471,44 @@ def _one_event_delta(problem: MTLProblem, cfg: AMTLConfig,
                 rank=cfg.prox_rank, key=k_prox)
         return backward(problem, v_hat, cfg.eta)
 
-    if cfg.prox_every <= 1:
-        p = refresh(None)
-        p_cache = state.p_cache      # untouched loop carry: no copy
-    else:
-        do_prox = (state.event % cfg.prox_every) == 0
-        p = jax.lax.cond(do_prox, refresh, lambda _: state.p_cache, None)
-        p_cache = p
+    with jax.named_scope("amtl.prox"):
+        # The sketch key, like the minibatch seed, is folded off the
+        # pre-event key (at a different constant).
+        k_prox = jax.random.fold_in(state.key, 7) if use_randomized else None
+        if cfg.prox_every <= 1:
+            p = refresh(None)
+            p_cache = state.p_cache      # untouched loop carry: no copy
+        else:
+            do_prox = (state.event % cfg.prox_every) == 0
+            p = jax.lax.cond(do_prox, refresh, lambda _: state.p_cache, None)
+            p_cache = p
 
-    p_t = p[:, t]
-    if cfg.batch_size is None:
-        g_t = problem.task_grad(t, p_t)
-    else:
-        g_t = problem.task_grad_sampled(t, p_t, mb_seed, cfg.batch_size)
+    with jax.named_scope("amtl.grad"):
+        p_t = p[:, t]
+        if cfg.batch_size is None:
+            g_t = problem.task_grad(t, p_t)
+        else:
+            g_t = problem.task_grad_sampled(t, p_t, mb_seed, cfg.batch_size)
 
-    history, eta_k = _km_relaxation(cfg, state.history, t, nu)
+    with jax.named_scope("amtl.update"):
+        history, eta_k = _km_relaxation(cfg, state.history, t, nu)
 
-    # Fused column event: forward step + KM relaxation + undo-log emit.
-    v_t_new, old_col = amtl_event(v[:, t], p_t, g_t,
-                                  jnp.asarray(cfg.eta, p_t.dtype),
-                                  eta_k.astype(p_t.dtype))
+        # Fused column event: forward step + KM relaxation + undo-log emit.
+        v_t_new, old_col = amtl_event(v[:, t], p_t, g_t,
+                                      jnp.asarray(cfg.eta, p_t.dtype),
+                                      eta_k.astype(p_t.dtype))
 
-    ptr = (state.ptr + 1) % depth
-    return DeltaAMTLState(
-        v=v.at[:, t].set(v_t_new),
-        delta_ring=state.delta_ring.at[ptr].set(old_col),
-        task_ring=state.task_ring.at[ptr].set(t),
-        ptr=ptr,
-        event=state.event + 1,
-        p_cache=p_cache,
-        history=history,
-        key=key,
-    )
+        ptr = (state.ptr + 1) % depth
+        return DeltaAMTLState(
+            v=v.at[:, t].set(v_t_new),
+            delta_ring=state.delta_ring.at[ptr].set(old_col),
+            task_ring=state.task_ring.at[ptr].set(t),
+            ptr=ptr,
+            event=state.event + 1,
+            p_cache=p_cache,
+            history=history,
+            key=key,
+        )
 
 
 def _one_batch(problem: MTLProblem, cfg: AMTLConfig, delay_offsets: Array,
@@ -524,11 +528,10 @@ def _one_batch(problem: MTLProblem, cfg: AMTLConfig, delay_offsets: Array,
     depth = cfg.tau + 1
     bsz = cfg.event_batch
     use_randomized = cfg.prox_rank is not None and problem.reg_name == "nuclear"
-    # Folded off the batch-start key — the key the serial engine would hold
-    # at its refresh event (a refresh batch's first event).
-    k_prox = jax.random.fold_in(state.key, 7) if use_randomized else None
-    key, ts, nus, mb_seeds = _sample_activation_batch(
-        cfg, delay_offsets, state.key, problem.num_tasks, state.event, bsz)
+    with jax.named_scope("amtl.sample"):
+        key, ts, nus, mb_seeds = _sample_activation_batch(
+            cfg, delay_offsets, state.key, problem.num_tasks, state.event,
+            bsz)
     v = state.v
 
     # Server prox at the batch's first event: stale read at staleness nu_0
@@ -544,18 +547,22 @@ def _one_batch(problem: MTLProblem, cfg: AMTLConfig, delay_offsets: Array,
                                   rank=cfg.prox_rank, key=k_prox)
         return backward(problem, v_hat, cfg.eta)
 
-    if cfg.prox_every <= bsz:
-        # Aligned cadence: refresh unconditionally every batch; the (0, 0)
-        # cache stub rides the carry untouched (no copy).
-        p = refresh(None)
-        p_cache = state.p_cache
-    else:
-        # Decoupled cadence: refresh only at every k-th batch's first
-        # event — exactly the events where the serial delta engine at the
-        # same prox_every refreshes — else reuse the carried cache.
-        do_prox = (state.event % cfg.prox_every) == 0
-        p = jax.lax.cond(do_prox, refresh, lambda _: state.p_cache, None)
-        p_cache = p
+    with jax.named_scope("amtl.prox"):
+        # Folded off the batch-start key — the key the serial engine would
+        # hold at its refresh event (a refresh batch's first event).
+        k_prox = jax.random.fold_in(state.key, 7) if use_randomized else None
+        if cfg.prox_every <= bsz:
+            # Aligned cadence: refresh unconditionally every batch; the
+            # (0, 0) cache stub rides the carry untouched (no copy).
+            p = refresh(None)
+            p_cache = state.p_cache
+        else:
+            # Decoupled cadence: refresh only at every k-th batch's first
+            # event — exactly the events where the serial delta engine at
+            # the same prox_every refreshes — else reuse the carried cache.
+            do_prox = (state.event % cfg.prox_every) == 0
+            p = jax.lax.cond(do_prox, refresh, lambda _: state.p_cache, None)
+            p_cache = p
 
     # Per-event forward-step gradients at the batch-constant prox.  g_t
     # depends only on (t, p[:, t]) — not on v — so duplicates need no
@@ -563,52 +570,56 @@ def _one_batch(problem: MTLProblem, cfg: AMTLConfig, delay_offsets: Array,
     # the serial engine, keeping the bits identical.  With batch_size set
     # each event samples its minibatch from the seed the serial delta
     # engine would derive at that chain position.
-    p_cols = p[:, ts]                                        # (d, bsz)
+    with jax.named_scope("amtl.grad"):
+        p_cols = p[:, ts]                                    # (d, bsz)
 
-    if cfg.batch_size is None:
-        def grad_one(_, inp):
-            t, p_t = inp
-            return None, problem.task_grad(t, p_t)
+        if cfg.batch_size is None:
+            def grad_one(_, inp):
+                t, p_t = inp
+                return None, problem.task_grad(t, p_t)
 
-        _, g_rows = jax.lax.scan(grad_one, None, (ts, p_cols.T))  # (bsz, d)
-    else:
-        def grad_one(_, inp):
-            t, p_t, s = inp
-            return None, problem.task_grad_sampled(t, p_t, s,
-                                                   cfg.batch_size)
+            _, g_rows = jax.lax.scan(grad_one, None, (ts, p_cols.T))
+        else:
+            def grad_one(_, inp):
+                t, p_t, s = inp
+                return None, problem.task_grad_sampled(t, p_t, s,
+                                                       cfg.batch_size)
 
-        _, g_rows = jax.lax.scan(grad_one, None, (ts, p_cols.T, mb_seeds))
+            _, g_rows = jax.lax.scan(grad_one, None,
+                                     (ts, p_cols.T, mb_seeds))  # (bsz, d)
 
-    # Delay recording / KM relaxation factors, in event order.
-    def relax_one(h, inp):
-        t, nu = inp
-        h, eta_k = _km_relaxation(cfg, h, t, nu)
-        return h, eta_k
+    with jax.named_scope("amtl.update"):
+        # Delay recording / KM relaxation factors, in event order.
+        def relax_one(h, inp):
+            t, nu = inp
+            h, eta_k = _km_relaxation(cfg, h, t, nu)
+            return h, eta_k
 
-    history, eta_ks = jax.lax.scan(relax_one, state.history, (ts, nus))
+        history, eta_ks = jax.lax.scan(relax_one, state.history, (ts, nus))
 
-    # Batched column updates: gather -> fused forward/KM/undo-emit ->
-    # scatter, duplicates serialized in event order inside the op.
-    v_new, undo_cols = amtl_event_batch(
-        v, p_cols, g_rows.T, ts, jnp.asarray(cfg.eta, v.dtype),
-        eta_ks.astype(v.dtype))
+        # Batched column updates: gather -> fused forward/KM/undo-emit ->
+        # scatter, duplicates serialized in event order inside the op.
+        v_new, undo_cols = amtl_event_batch(
+            v, p_cols, g_rows.T, ts, jnp.asarray(cfg.eta, v.dtype),
+            eta_ks.astype(v.dtype))
 
-    # Ring append, batched.  Only the newest `depth` events can ever be
-    # rolled back (nu <= tau < depth), so when bsz > depth the overwritten
-    # head of the batch is dropped; the surviving slots are distinct and
-    # the scatter is deterministic.
-    keep = min(bsz, depth)
-    slots = (state.ptr + 1 + jnp.arange(bsz - keep, bsz)) % depth
-    return BatchAMTLState(
-        v=v_new,
-        delta_ring=state.delta_ring.at[slots].set(undo_cols[bsz - keep:]),
-        task_ring=state.task_ring.at[slots].set(ts[bsz - keep:]),
-        ptr=(state.ptr + bsz) % depth,
-        event=state.event + bsz,
-        p_cache=p_cache,
-        history=history,
-        key=key,
-    )
+        # Ring append, batched.  Only the newest `depth` events can ever be
+        # rolled back (nu <= tau < depth), so when bsz > depth the
+        # overwritten head of the batch is dropped; the surviving slots are
+        # distinct and the scatter is deterministic.
+        keep = min(bsz, depth)
+        slots = (state.ptr + 1 + jnp.arange(bsz - keep, bsz)) % depth
+        return BatchAMTLState(
+            v=v_new,
+            delta_ring=state.delta_ring.at[slots].set(
+                undo_cols[bsz - keep:]),
+            task_ring=state.task_ring.at[slots].set(ts[bsz - keep:]),
+            ptr=(state.ptr + bsz) % depth,
+            event=state.event + bsz,
+            p_cache=p_cache,
+            history=history,
+            key=key,
+        )
 
 
 def _sharded_state_specs(cfg: AMTLConfig,
@@ -678,13 +689,11 @@ def _one_batch_sharded(problem: MTLProblem, cfg: AMTLConfig,
 
     def local_body(problem_l, offs, st):
         t_off = jax.lax.axis_index(axis) * n_local
-        # Folded off the batch-start key, replicated — identical to the
-        # serial engines' sketch key.
-        k_prox = jax.random.fold_in(st.key, 7) if use_randomized else None
-        key, ts, nus, mb_seeds = _sample_activation_batch(
-            cfg, offs, st.key, num_tasks, st.event, bsz)
-        lts, owned = shard_local_tasks(ts, t_off, n_local)
-        lts_clamped = jnp.where(owned, lts, 0)
+        with jax.named_scope("amtl.sample"):
+            key, ts, nus, mb_seeds = _sample_activation_batch(
+                cfg, offs, st.key, num_tasks, st.event, bsz)
+            lts, owned = shard_local_tasks(ts, t_off, n_local)
+            lts_clamped = jnp.where(owned, lts, 0)
         v = st.v                                   # (d, n_local)
         ring = st.delta_ring[0]                    # (depth, d) private ring
 
@@ -719,74 +728,85 @@ def _one_batch_sharded(problem: MTLProblem, cfg: AMTLConfig,
                                       key=k_prox)
             return backward(problem_l, v_hat, cfg.eta)
 
-        if cfg.prox_every <= bsz:
-            p = refresh(None)
-            p_cache = st.p_cache
-        else:
-            do_prox = (st.event % cfg.prox_every) == 0
-            p = jax.lax.cond(do_prox, refresh, lambda _: st.p_cache, None)
-            p_cache = p
+        with jax.named_scope("amtl.prox"):
+            # Folded off the batch-start key, replicated — identical to
+            # the serial engines' sketch key.
+            k_prox = jax.random.fold_in(st.key, 7) if use_randomized \
+                else None
+            if cfg.prox_every <= bsz:
+                p = refresh(None)
+                p_cache = st.p_cache
+            else:
+                do_prox = (st.event % cfg.prox_every) == 0
+                p = jax.lax.cond(do_prox, refresh, lambda _: st.p_cache,
+                                 None)
+                p_cache = p
 
-        # Per-event prox columns.  The replicated prox yields the global
-        # (d, T) result, indexed by global task id; the distributed prox
-        # yields only this shard's (d, n_local) block, indexed by local
-        # column id (foreign events read the clamped column 0 — their
-        # whole pipeline is dropped at the scatter).  On the owner shard
-        # both index the same bits of the same reconstruction.
-        p_cols = p[:, lts_clamped] if distributed else p[:, ts]  # (d, bsz)
+        with jax.named_scope("amtl.grad"):
+            # Per-event prox columns.  The replicated prox yields the
+            # global (d, T) result, indexed by global task id; the
+            # distributed prox yields only this shard's (d, n_local)
+            # block, indexed by local column id (foreign events read the
+            # clamped column 0 — their whole pipeline is dropped at the
+            # scatter).  On the owner shard both index the same bits of
+            # the same reconstruction.
+            p_cols = p[:, lts_clamped] if distributed else p[:, ts]
 
-        # Forward-step gradients from the shard-local task data.  Foreign
-        # events run on clamped inputs and are dropped at the scatter; the
-        # owner's expression is the serial engines', on the same bits.
-        # Minibatch seeds come from the replicated chain replay, so the
-        # owner samples the same rows of its task's (shard-local) data the
-        # unsharded engine would at any shard count.
-        if cfg.batch_size is None:
-            def grad_one(_, inp):
-                t_l, p_t = inp
-                return None, problem_l.task_grad(t_l, p_t)
+            # Forward-step gradients from the shard-local task data.
+            # Foreign events run on clamped inputs and are dropped at the
+            # scatter; the owner's expression is the serial engines', on
+            # the same bits.  Minibatch seeds come from the replicated
+            # chain replay, so the owner samples the same rows of its
+            # task's (shard-local) data the unsharded engine would at any
+            # shard count.
+            if cfg.batch_size is None:
+                def grad_one(_, inp):
+                    t_l, p_t = inp
+                    return None, problem_l.task_grad(t_l, p_t)
 
-            _, g_rows = jax.lax.scan(grad_one, None,
-                                     (lts_clamped, p_cols.T))
-        else:
-            def grad_one(_, inp):
-                t_l, p_t, s = inp
-                return None, problem_l.task_grad_sampled(t_l, p_t, s,
-                                                         cfg.batch_size)
+                _, g_rows = jax.lax.scan(grad_one, None,
+                                         (lts_clamped, p_cols.T))
+            else:
+                def grad_one(_, inp):
+                    t_l, p_t, s = inp
+                    return None, problem_l.task_grad_sampled(
+                        t_l, p_t, s, cfg.batch_size)
 
-            _, g_rows = jax.lax.scan(grad_one, None,
-                                     (lts_clamped, p_cols.T, mb_seeds))
+                _, g_rows = jax.lax.scan(grad_one, None,
+                                         (lts_clamped, p_cols.T, mb_seeds))
 
-        # Delay recording / KM relaxation in event order; only the owner
-        # keeps each event's history write.
-        def relax_one(h, inp):
-            t_l, nu, own = inp
-            h2, eta_k = _km_relaxation(cfg, h, t_l, nu)
-            h = jax.tree.map(lambda a, b: jnp.where(own, a, b), h2, h)
-            return h, eta_k
+        with jax.named_scope("amtl.update"):
+            # Delay recording / KM relaxation in event order; only the
+            # owner keeps each event's history write.
+            def relax_one(h, inp):
+                t_l, nu, own = inp
+                h2, eta_k = _km_relaxation(cfg, h, t_l, nu)
+                h = jax.tree.map(lambda a, b: jnp.where(own, a, b), h2, h)
+                return h, eta_k
 
-        history, eta_ks = jax.lax.scan(relax_one, st.history,
-                                       (lts_clamped, nus, owned))
+            history, eta_ks = jax.lax.scan(relax_one, st.history,
+                                           (lts_clamped, nus, owned))
 
-        # Shard-local batched column updates (foreign events -> sentinel
-        # column, dropped inside the op) and private-ring append; the task
-        # ring records global ids so later rollbacks can re-mask ownership.
-        v_new, undo_cols = amtl_event_batch_sharded(
-            v, p_cols, g_rows.T, lts, jnp.asarray(cfg.eta, v.dtype),
-            eta_ks.astype(v.dtype))
+            # Shard-local batched column updates (foreign events ->
+            # sentinel column, dropped inside the op) and private-ring
+            # append; the task ring records global ids so later rollbacks
+            # can re-mask ownership.
+            v_new, undo_cols = amtl_event_batch_sharded(
+                v, p_cols, g_rows.T, lts, jnp.asarray(cfg.eta, v.dtype),
+                eta_ks.astype(v.dtype))
 
-        keep = min(bsz, depth)
-        slots = (st.ptr + 1 + jnp.arange(bsz - keep, bsz)) % depth
-        return ShardedAMTLState(
-            v=v_new,
-            delta_ring=ring.at[slots].set(undo_cols[bsz - keep:])[None],
-            task_ring=st.task_ring.at[slots].set(ts[bsz - keep:]),
-            ptr=(st.ptr + bsz) % depth,
-            event=st.event + bsz,
-            p_cache=p_cache,
-            history=history,
-            key=key,
-        )
+            keep = min(bsz, depth)
+            slots = (st.ptr + 1 + jnp.arange(bsz - keep, bsz)) % depth
+            return ShardedAMTLState(
+                v=v_new,
+                delta_ring=ring.at[slots].set(undo_cols[bsz - keep:])[None],
+                task_ring=st.task_ring.at[slots].set(ts[bsz - keep:]),
+                ptr=(st.ptr + bsz) % depth,
+                event=st.event + bsz,
+                p_cache=p_cache,
+                history=history,
+                key=key,
+            )
 
     sp = task_shard_specs(axis)
     state_specs = _sharded_state_specs(cfg, axis)
@@ -1009,8 +1029,10 @@ def make_engine(problem: MTLProblem, cfg: AMTLConfig,
                 f"event_batch ({per_step}) for engine={cfg.engine!r}")
         if delay_offsets is None:
             delay_offsets = jnp.zeros((num_tasks,), jnp.float32)
-        return _run_events(problem, cfg, state, delay_offsets,
-                           int(num_events), mesh)
+        with jax.profiler.TraceAnnotation("amtl.run",
+                                          num_events=int(num_events)):
+            return _run_events(problem, cfg, state, delay_offsets,
+                               int(num_events), mesh)
 
     return AMTLEngine(init=init, run=run, iterate=current_iterate,
                       events_per_step=per_step, num_tasks=num_tasks)

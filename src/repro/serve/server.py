@@ -395,21 +395,23 @@ class AMTLServer:
         t0 = time.perf_counter() if self._slo is not None else 0.0
         cap = self.serve_cfg.max_batch
         outs = []
-        for lo in range(0, t.shape[0], cap):
-            ts = t[lo:lo + cap]
-            xs = x[lo:lo + cap]
-            m = _bucket(ts.shape[0], cap)
-            pad = m - ts.shape[0]
-            if pad:
-                ts = np.pad(ts, (0, pad))
-                xs = jnp.pad(xs, ((0, pad), (0, 0)))
-            scores = _predict_scores(snap.v, jnp.asarray(ts), xs,
-                                     self.problem.loss_name)
-            outs.append(scores[:m - pad] if pad else scores)
-        out = outs[0] if len(outs) == 1 else jnp.concatenate(outs)
-        if self._slo is not None:
-            jax.block_until_ready(out)        # latency = computed scores
-            self._slo.record(1e3 * (time.perf_counter() - t0))
+        with jax.profiler.TraceAnnotation("serve.predict",
+                                          rows=int(t.shape[0])):
+            for lo in range(0, t.shape[0], cap):
+                ts = t[lo:lo + cap]
+                xs = x[lo:lo + cap]
+                m = _bucket(ts.shape[0], cap)
+                pad = m - ts.shape[0]
+                if pad:
+                    ts = np.pad(ts, (0, pad))
+                    xs = jnp.pad(xs, ((0, pad), (0, 0)))
+                scores = _predict_scores(snap.v, jnp.asarray(ts), xs,
+                                         self.problem.loss_name)
+                outs.append(scores[:m - pad] if pad else scores)
+            out = outs[0] if len(outs) == 1 else jnp.concatenate(outs)
+            if self._slo is not None:
+                jax.block_until_ready(out)    # latency = computed scores
+                self._slo.record(1e3 * (time.perf_counter() - t0))
         return out
 
     def iterate(self) -> Array:
@@ -578,16 +580,21 @@ class AMTLServer:
             rows, self._pending_rows = self._pending_rows, []
         if not rows:
             return None
-        created = self._store is None
-        if created:
-            self._store = TaskStore.from_problem(self.problem)
-        tids = np.asarray([r[0] for r in rows], np.int64)
-        xs = np.stack([r[1] for r in rows])
-        ys = np.asarray([r[2] for r in rows], np.float32)
-        prev = (self.problem, self.engine)
-        store_undo = self._store.append_undoable(tids, xs, ys)
-        self.problem = self._store.problem()
-        self.engine = make_engine(self.problem, self.cfg, self._mesh)
+        with jax.profiler.TraceAnnotation("serve.fold") as span:
+            created = self._store is None
+            if created:
+                self._store = TaskStore.from_problem(self.problem)
+            tids = np.asarray([r[0] for r in rows], np.int64)
+            xs = np.stack([r[1] for r in rows])
+            ys = np.asarray([r[2] for r in rows], np.float32)
+            prev = (self.problem, self.engine)
+            store_undo = self._store.append_undoable(tids, xs, ys)
+            self.problem = self._store.problem()
+            self.engine = make_engine(self.problem, self.cfg, self._mesh)
+            # `bytes`: the store arrays the fold rebuilt on the device.
+            span.set_metadata(rows=len(rows), bytes=sum(
+                a.nbytes for a in (self.problem.xs, self.problem.ys,
+                                   self.problem.row_counts)))
         return (store_undo, prev[0], prev[1], created)
 
     def _unfold_rows(self, fold: Optional[tuple]) -> None:
@@ -628,19 +635,27 @@ class AMTLServer:
         quarantined), so drain loops always make progress past a
         poisoned chunk.
         """
-        with self._state_lock:
+        # `serve.chunk` carries `events` (0 at an idle boundary) and, for
+        # a chunk that runs, its index `chunk`.
+        with self._state_lock, \
+                jax.profiler.TraceAnnotation("serve.chunk") as span:
             fold = self._fold_pending_rows()
-            taken = self._coalesce()
+            with jax.profiler.TraceAnnotation("serve.coalesce"):
+                taken = self._coalesce()
             n = int(taken.sum())
             if n == 0:
+                span.set_metadata(events=0)
                 return 0
             chunk_idx = self._faults.begin_chunk()
+            span.set_metadata(chunk=chunk_idx, events=n)
             self._faults.crash_point(chunk_idx)   # scripted learner crash
             state = self.engine.run(self._state, self._delay_offsets, n)
             v = self.engine.iterate(state)
             v = self._faults.poison(chunk_idx, v)  # scripted NaN iterate
-            v = jax.block_until_ready(v)
-            if not bool(jnp.isfinite(v).all()):
+            with jax.profiler.TraceAnnotation("serve.guard"):
+                v = jax.block_until_ready(v)
+                finite = bool(jnp.isfinite(v).all())
+            if not finite:
                 # Quarantine: nothing commits.  The last committed
                 # snapshot keeps serving, the fold unwinds bitwise, and
                 # the chunk's events are logged per task — never

@@ -307,3 +307,40 @@ def test_validate_config_standalone(small_problem):
                          prox_every=12))
     with pytest.raises(ValueError, match="prox_every must be >= 1"):
         validate_config(_cfg(small_problem, "delta", prox_every=0))
+
+
+# ------------------------------------------------------------ phase names
+@pytest.mark.parametrize("engine", ("delta", "batch", "sharded"))
+def test_engine_step_names_its_phases(small_problem, mesh1, engine):
+    """The sampling, prox, gradient and update phases are named scopes of
+    the lowered program (what a device trace attributes time to)."""
+    from repro.core.amtl import _run_events
+
+    cfg = _cfg(small_problem, engine, prox_rank=2, batch_size=8)
+    eng = _engine_for(small_problem, cfg, mesh1)
+    w0 = jnp.zeros((small_problem.dim, small_problem.num_tasks), jnp.float32)
+    state = eng.init(w0, jax.random.PRNGKey(0))
+    text = _run_events.lower(
+        small_problem, cfg, state, jnp.zeros((small_problem.num_tasks,)),
+        2 * eng.events_per_step,
+        mesh1 if engine == "sharded" else None).as_text(debug_info=True)
+    for scope in ("amtl.sample", "amtl.prox", "amtl.grad", "amtl.update"):
+        assert scope in text, scope
+
+
+def test_run_opens_a_host_span_with_its_event_count(small_problem, tmp_path):
+    from jax.profiler import ProfileData
+
+    cfg = _cfg(small_problem, "batch")
+    eng = make_engine(small_problem, cfg)
+    w0 = jnp.zeros((small_problem.dim, small_problem.num_tasks), jnp.float32)
+    state = eng.init(w0, jax.random.PRNGKey(0))
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        jax.block_until_ready(eng.run(state, None, 8))
+    finally:
+        jax.profiler.stop_trace()
+    data = ProfileData.from_file(str(next(tmp_path.rglob("*.xplane.pb"))))
+    runs = [dict(ev.stats) for plane in data.planes for line in plane.lines
+            for ev in line.events if ev.name == "amtl.run"]
+    assert runs == [{"num_events": 8}]

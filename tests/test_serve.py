@@ -381,3 +381,47 @@ def test_stats_telemetry(small_problem, mesh1):
     assert s["requests"] == 1 and s["predictions"] == 3
     assert s["events"] == 2 and s["chunks"] == 1
     assert s["learning"] is True
+
+
+# ------------------------------------------------------------ host spans
+def test_a_chunk_boundary_opens_its_spans_with_their_counts(small_problem,
+                                                            mesh1, tmp_path):
+    """A profile of one `step()` with labeled feedback holds `serve.chunk`
+    around the fold, the coalesce, the engine run and the guard, each with
+    its arguments; a predict opens `serve.predict` with its rows."""
+    from jax.profiler import ProfileData
+
+    server = _server(small_problem, _cfg(small_problem, "batch"), mesh1,
+                     ServeConfig(chunk_events=8))
+    t, x = _requests(small_problem, 8, seed=3)
+    y = np.ones(8, np.float32)
+    assert server.submit_feedback(t, x, y).accepted == 8
+    server.step()                      # compiles outside the profile
+    server.submit_feedback(t, x, y)
+    server.predict(t, x)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        assert server.step() == 8
+        jax.block_until_ready(server.predict(t[:5], x[:5]))
+    finally:
+        jax.profiler.stop_trace()
+    data = ProfileData.from_file(str(next(tmp_path.rglob("*.xplane.pb"))))
+    spans = {ev.name: (ev.start_ns, ev.start_ns + ev.duration_ns,
+                       dict(ev.stats))
+             for plane in data.planes for line in plane.lines
+             for ev in line.events
+             if ev.name.startswith(("serve.", "amtl."))}
+    assert set(spans) == {"serve.chunk", "serve.fold", "serve.coalesce",
+                          "amtl.run", "serve.guard", "serve.predict"}
+    lo, hi, chunk = spans["serve.chunk"]
+    assert chunk == {"chunk": 1, "events": 8}
+    for name in ("serve.fold", "serve.coalesce", "amtl.run", "serve.guard"):
+        s, e, _ = spans[name]
+        assert lo <= s and e <= hi, name
+    store = server.problem
+    assert spans["serve.fold"][2] == {
+        "rows": 8, "bytes": store.xs.nbytes + store.ys.nbytes
+        + store.row_counts.nbytes}
+    assert spans["amtl.run"][2] == {"num_events": 8}
+    assert spans["serve.predict"][2] == {"rows": 5}
+    assert not (lo <= spans["serve.predict"][0] < hi)

@@ -169,3 +169,43 @@ def test_sharded_engine_step_compiles_for_v5e_2x2(topo, monkeypatch,
                                  mesh).compile()
     _assert_pallas(compiled)
     assert "all-gather" in compiled.as_text()
+
+
+PHASES = ("amtl.sample", "amtl.prox", "amtl.grad", "amtl.update")
+
+
+@pytest.mark.parametrize("engine", ("batch", "replicated", "distributed"))
+def test_engine_phases_are_named_in_the_program_for_v5e(topo, one_chip,
+                                                        monkeypatch, engine):
+    """Each engine phase is a named scope in the lowered `_run_events`, so
+    a device trace attributes every op of it to its phase."""
+    monkeypatch.setattr(ops, "_on_tpu", lambda: True)
+    if engine == "batch":
+        problem, cfg, state, offs = _engine_args(one_chip, D_G, T_G, N_G,
+                                                 ragged=True)
+        mesh = None
+    else:
+        mesh = Mesh(np.asarray(topo.devices), (TASK_AXIS,))
+        cfg = AMTLConfig(eta=0.1, eta_k=amtl_max_step(TAU, T), tau=TAU,
+                         engine="sharded", event_batch=EVENT_BATCH,
+                         prox_every=EVENT_BATCH, prox_rank=PROX_RANK,
+                         prox_mode=engine, batch_size=BATCH_SIZE)
+        on_mesh = lambda a, spec: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=NamedSharding(mesh, spec))
+        state = jax.tree.map(on_mesh, jax.eval_shape(
+            lambda v0, k: init_sharded_state(cfg, v0, T, k, len(topo.devices)),
+            jax.ShapeDtypeStruct((D_G, T), jnp.float32),
+            jax.ShapeDtypeStruct((2,), jnp.uint32)), _sharded_state_specs(cfg))
+        sp = task_shard_specs()
+        problem = MTLProblem(
+            on_mesh(jax.ShapeDtypeStruct((T, N_G, D_G), jnp.float32),
+                    sp["per_task"]),
+            on_mesh(jax.ShapeDtypeStruct((T, N_G), jnp.float32),
+                    sp["per_task"]),
+            "lstsq", "nuclear", 0.1)
+        offs = on_mesh(jax.ShapeDtypeStruct((T,), jnp.float32),
+                       sp["replicated"])
+    text = _run_events.lower(problem, cfg, state, offs, 2 * EVENT_BATCH,
+                             mesh).as_text(debug_info=True)
+    for scope in PHASES:
+        assert scope in text, scope
