@@ -348,6 +348,15 @@ def _sample_activation(cfg: AMTLConfig, delay_offsets: Array, key: Array,
     event sequences across `engine=` choices.
     """
     key, k_task, k_delay = jax.random.split(key, 3)
+    t, nu = _event_draw(cfg, delay_offsets, num_tasks, k_task, k_delay, event)
+    return key, t, nu
+
+
+def _event_draw(cfg: AMTLConfig, delay_offsets: Array, num_tasks: int,
+                k_task: Array, k_delay: Array, event: Array):
+    """The per-event draw law: (activated task, staleness nu) from the two
+    keys one chain step splits off.  The one-event engines call it
+    directly and the batch sampler vmaps it, so both draw alike."""
     # Assumption 1: same-rate independent Poisson processes => the next
     # activated node is uniform over tasks.
     t = jax.random.randint(k_task, (), 0, num_tasks)
@@ -355,7 +364,7 @@ def _sample_activation(cfg: AMTLConfig, delay_offsets: Array, key: Array,
     raw = delay_offsets[t] + cfg.delay_jitter * jax.random.uniform(k_delay)
     nu = jnp.minimum(jnp.round(raw).astype(jnp.int32),
                      jnp.minimum(cfg.tau, event))
-    return key, t, nu
+    return t, nu
 
 
 def _minibatch_seed(key: Array) -> Array:
@@ -374,24 +383,30 @@ def _minibatch_seed(key: Array) -> Array:
 def _sample_activation_batch(cfg: AMTLConfig, delay_offsets: Array,
                              key: Array, num_tasks: int, event: Array,
                              batch: int):
-    """Replay `batch` steps of the serial PRNG chain in one scan.
+    """Replay `batch` steps of the serial PRNG chain without a device loop.
 
     Same splits, same draws, same staleness clamp (`event + i`) as `batch`
     consecutive calls of `_sample_activation` — the event stream is
-    identical to the one-event engines by construction.  Returns
+    identical to the one-event engines by construction.  Only the chain
+    `key_{i+1} = split(key_i, 3)[0]` is serial, and it is unrolled at
+    trace time (`batch` is static); every draw is a pure function of one
+    chain key, so the draws are vmapped over the batch.  Returns
     (next key, tasks (batch,), stalenesses (batch,), minibatch seeds
     (batch,) uint32).  Each seed is `_minibatch_seed` of the chain key
     the serial delta engine would hold at that event, so the one-event
     and batched SGD engines sample identical minibatches; when
     batch_size is None the seeds are unused (and dead-code-eliminated).
     """
-    def one(k, i):
-        seed = _minibatch_seed(k)
-        k, t, nu = _sample_activation(cfg, delay_offsets, k, num_tasks,
-                                      event + i)
-        return k, (t, nu, seed)
-
-    key, (ts, nus, seeds) = jax.lax.scan(one, key, jnp.arange(batch))
+    pre, k_tasks, k_delays = [], [], []
+    for _ in range(batch):
+        pre.append(key)
+        key, k_task, k_delay = jax.random.split(key, 3)
+        k_tasks.append(k_task)
+        k_delays.append(k_delay)
+    draw = functools.partial(_event_draw, cfg, delay_offsets, num_tasks)
+    ts, nus = jax.vmap(draw)(jnp.stack(k_tasks), jnp.stack(k_delays),
+                             event + jnp.arange(batch))
+    seeds = jax.vmap(_minibatch_seed)(jnp.stack(pre))
     return key, ts, nus, seeds
 
 

@@ -84,6 +84,55 @@ def test_batch_sampler_replays_serial_chain_exactly(setup):
                for i, nu in enumerate(want_nus))
 
 
+# The cell's sampler shape (bench/configs/emnist62_writers.json): 3400
+# writers, tau 8, jitter 1.0, non-zero per-task delay offsets.
+_CELL_T, _CELL_TAU = 3400, 8
+
+
+@pytest.mark.parametrize("event0", (0, 5, 3392))
+@pytest.mark.parametrize("batch", (1, 8, 32))
+def test_batch_sampler_replays_serial_chain_at_cell_shape(batch, event0):
+    """At the shape the benchmark runs, the batch sampler's unrolled chain
+    and vmapped draws equal `batch` serial `_sample_activation` calls bit
+    for bit: keys, tasks, stalenesses, seeds, and the chain head."""
+    cfg = AMTLConfig(eta=0.1, eta_k=0.5, tau=_CELL_TAU, delay_jitter=1.0)
+    offs = jax.random.uniform(jax.random.PRNGKey(3), (_CELL_T,),
+                              minval=0.0, maxval=6.0)
+    key0 = jax.random.PRNGKey(2**31 - 17)
+    serial = jax.jit(_sample_activation, static_argnums=(0, 3))
+
+    key = key0
+    want_ts, want_nus, want_seeds = [], [], []
+    for i in range(batch):
+        want_seeds.append(int(_minibatch_seed(key)))
+        key, t, nu = serial(cfg, offs, key, _CELL_T,
+                            jnp.asarray(event0 + i, jnp.int32))
+        want_ts.append(int(t))
+        want_nus.append(int(nu))
+
+    got_key, got_ts, got_nus, got_seeds = jax.jit(
+        _sample_activation_batch, static_argnums=(0, 3, 5))(
+        cfg, offs, key0, _CELL_T, jnp.asarray(event0, jnp.int32), batch)
+
+    np.testing.assert_array_equal(np.asarray(got_ts), want_ts)
+    np.testing.assert_array_equal(np.asarray(got_nus), want_nus)
+    np.testing.assert_array_equal(np.asarray(got_seeds), want_seeds)
+    np.testing.assert_array_equal(np.asarray(got_key), np.asarray(key))
+
+
+def test_batch_sampler_lowers_without_a_device_loop():
+    """The batch's draws are straight-line code: the sampler the chip runs
+    holds no `while`.  Lowered for TPU because on the CPU backend threefry
+    itself lowers to a rolled loop."""
+    cfg = AMTLConfig(eta=0.1, eta_k=0.5, tau=_CELL_TAU, delay_jitter=1.0)
+    text = jax.jit(_sample_activation_batch, static_argnums=(0, 3, 5)).trace(
+        cfg, jnp.zeros((_CELL_T,), jnp.float32), jax.random.PRNGKey(0),
+        _CELL_T, jnp.asarray(0, jnp.int32), 32,
+    ).lower(lowering_platforms=("tpu",)).as_text()
+    assert "threefry" in text
+    assert "stablehlo.while" not in text
+
+
 # ------------------------------------------------- session split / resume
 
 _T, _N, _D = 4, 6, 8

@@ -36,6 +36,7 @@ from repro.kernels import ops
 D, T, N, TAU, EVENT_BATCH, PROX_RANK = 8192, 128, 4, 8, 32, 16
 D_G, T_G, N_G, BATCH_SIZE = 4096, 32, 512, 32
 D_S, T_S, N_S = 4096, 128, 512
+D_C, T_C, N_C = 784, 3400, 512      # bench/configs/emnist62_writers.json
 
 
 @pytest.fixture(scope="module")
@@ -174,14 +175,19 @@ def test_sharded_engine_step_compiles_for_v5e_2x2(topo, monkeypatch,
 PHASES = ("amtl.sample", "amtl.prox", "amtl.grad", "amtl.update")
 
 
-@pytest.mark.parametrize("engine", ("batch", "replicated", "distributed"))
+@pytest.mark.parametrize("engine", ("batch", "replicated", "distributed",
+                                    "batch_cell"))
 def test_engine_phases_are_named_in_the_program_for_v5e(topo, one_chip,
                                                         monkeypatch, engine):
     """Each engine phase is a named scope in the lowered `_run_events`, so
-    a device trace attributes every op of it to its phase."""
+    a device trace attributes every op of it to its phase.  `batch_cell`
+    is the batch engine at the benchmark cell's shape (3400 writers, 784
+    features, 512 rows), whose sampler draws the batch without a loop."""
     monkeypatch.setattr(ops, "_on_tpu", lambda: True)
-    if engine == "batch":
-        problem, cfg, state, offs = _engine_args(one_chip, D_G, T_G, N_G,
+    if engine.startswith("batch"):
+        d, t, n = (D_C, T_C, N_C) if engine == "batch_cell" \
+            else (D_G, T_G, N_G)
+        problem, cfg, state, offs = _engine_args(one_chip, d, t, n,
                                                  ragged=True)
         mesh = None
     else:
