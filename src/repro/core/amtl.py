@@ -737,7 +737,9 @@ def _one_batch_sharded(problem: MTLProblem, cfg: AMTLConfig,
                 return svt_randomized_dist(v_hat_loc2, thresh,
                                            rank=cfg.prox_rank, key=k_prox,
                                            plan=plan)
-            v_hat = jax.lax.all_gather(v_hat_loc2, axis, axis=1, tiled=True)
+            with jax.named_scope("comm.iterate_gather"):
+                v_hat = jax.lax.all_gather(v_hat_loc2, axis, axis=1,
+                                           tiled=True)
             if use_randomized:
                 return svt_randomized(v_hat, thresh, rank=cfg.prox_rank,
                                       key=k_prox)
@@ -978,12 +980,28 @@ def _iterate_metrics(problem: MTLProblem, cfg: AMTLConfig, v: Array):
                 fixed_point_residual(problem, v, cfg.eta))
 
 
+def refresh_comm_bytes(cfg: AMTLConfig, dim: int, num_tasks: int,
+                       n_shards: int | None) -> int:
+    """Bytes one prox refresh moves between the shards: the distributed
+    prox's psum and core gather (`ProxPlan.comm_bytes_per_refresh`), or the
+    replicated prox's (d, T) f32 iterate gather; 0 off the sharded engine
+    and on one shard."""
+    if n_shards is None or n_shards == 1:
+        return 0
+    if cfg.prox_mode == "distributed":
+        plan = ProxPlan(axis=TASK_AXIS, num_tasks=num_tasks,
+                        n_local=num_tasks // n_shards)
+        return plan.comm_bytes_per_refresh(dim, cfg.prox_rank)
+    return dim * num_tasks * 4
+
+
 class AMTLEngine(NamedTuple):
     """A resumable AMTL session: pure jittable functions over an engine
     state (the public stepwise API; `make_engine` builds one).
 
     init(v0, key) -> state
-        Fresh engine state for a (d, T) initial iterate and a PRNG key.
+        Fresh engine state for a (d, T) initial iterate and a PRNG key
+        (the sharded engine's placed on its mesh as `run` returns it).
     run(state, delay_offsets, num_events) -> state
         Advance the session by `num_events` activations (jitted; one
         compile per distinct num_events).  `delay_offsets` may be None
@@ -1027,6 +1045,7 @@ def make_engine(problem: MTLProblem, cfg: AMTLConfig,
     mesh, n_shards = _resolve_mesh(problem, cfg, mesh)
     num_tasks = problem.num_tasks
     per_step = cfg.event_batch if cfg.engine in ("batch", "sharded") else 1
+    per_refresh = refresh_comm_bytes(cfg, problem.dim, num_tasks, n_shards)
 
     def init(v0: Array, key: Array):
         if cfg.engine == "dense":
@@ -1035,7 +1054,14 @@ def make_engine(problem: MTLProblem, cfg: AMTLConfig,
             return init_delta_state(cfg, v0, num_tasks, key)
         if cfg.engine == "batch":
             return init_batch_state(cfg, v0, num_tasks, key)
-        return init_sharded_state(cfg, v0, num_tasks, key, n_shards)
+        # Placed as `run` returns it, so that the first call compiles the
+        # program every later call runs.
+        return jax.device_put(
+            init_sharded_state(cfg, v0, num_tasks, key, n_shards),
+            jax.tree.map(lambda spec: jax.sharding.NamedSharding(mesh, spec),
+                         _sharded_state_specs(cfg),
+                         is_leaf=lambda x: isinstance(
+                             x, jax.sharding.PartitionSpec)))
 
     def run(state, delay_offsets, num_events: int):
         if num_events % per_step != 0:
@@ -1044,8 +1070,12 @@ def make_engine(problem: MTLProblem, cfg: AMTLConfig,
                 f"event_batch ({per_step}) for engine={cfg.engine!r}")
         if delay_offsets is None:
             delay_offsets = jnp.zeros((num_tasks,), jnp.float32)
-        with jax.profiler.TraceAnnotation("amtl.run",
-                                          num_events=int(num_events)):
+        # `comm_bytes`: what the call's refreshes move between the shards
+        # (exact when the call starts on a refresh).
+        with jax.profiler.TraceAnnotation(
+                "amtl.run", num_events=int(num_events),
+                shards=n_shards or 1,
+                comm_bytes=int(num_events) // cfg.prox_every * per_refresh):
             return _run_events(problem, cfg, state, delay_offsets,
                                int(num_events), mesh)
 

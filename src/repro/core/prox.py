@@ -150,14 +150,19 @@ def svt_randomized_dist(w_local: Array, t: Array, *, rank: int, key: Array,
     # y = sum_s W_s @ Omega_s — ONE (d, p) psum; each shard's sketch flops
     # drop from O(d*T*p) to O(d*T*p / n_shards), and each shard only ever
     # generates its own (n_local, p) rows of Omega (in-kernel on TPU).
-    y = jax.lax.psum(
-        gauss_sketch(w_local, _sketch_seed(key), t_off, p=p), plan.axis)
+    # The two collectives carry `comm.*` scopes of their own, outside the
+    # engine's `amtl.*` phases, so a device trace names them by what they
+    # move.
+    y_loc = gauss_sketch(w_local, _sketch_seed(key), t_off, p=p)
+    with jax.named_scope("comm.sketch_psum"):
+        y = jax.lax.psum(y_loc, plan.axis)
     q, _ = jnp.linalg.qr(y)                                  # replicated
     b_loc = q.T @ w_local.astype(jnp.float32)                # (p, n_local)
     # Assemble the projected core with a tiny (p, n_local) all_gather; the
     # per-column contraction over d is shard-local, so given Q the gathered
     # core carries the serial `Q^T W` bits.
-    b = jax.lax.all_gather(b_loc, plan.axis, axis=1, tiled=True)
+    with jax.named_scope("comm.core_gather"):
+        b = jax.lax.all_gather(b_loc, plan.axis, axis=1, tiled=True)
     ub, s, vt = jnp.linalg.svd(b, full_matrices=False)       # replicated
     s = jnp.maximum(s - t, 0.0)
     vt_loc = jax.lax.dynamic_slice_in_dim(vt, t_off, plan.n_local, 1)

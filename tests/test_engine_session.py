@@ -343,4 +343,44 @@ def test_run_opens_a_host_span_with_its_event_count(small_problem, tmp_path):
     data = ProfileData.from_file(str(next(tmp_path.rglob("*.xplane.pb"))))
     runs = [dict(ev.stats) for plane in data.planes for line in plane.lines
             for ev in line.events if ev.name == "amtl.run"]
-    assert runs == [{"num_events": 8}]
+    assert runs == [{"num_events": 8, "shards": 1, "comm_bytes": 0}]
+
+
+
+PLACED = r"""
+import jax, jax.numpy as jnp
+from repro.core import AMTLConfig, MTLProblem, make_engine
+from repro.core.amtl import _run_events
+from repro.launch.mesh import make_task_mesh
+
+t, n, d = 8, 6, 5
+prob = MTLProblem(jnp.ones((t, n, d)), jnp.ones((t, n)), "lstsq", "nuclear",
+                  0.1)
+cfg = AMTLConfig(eta=0.1, eta_k=0.7, tau=3, engine="sharded", event_batch=4,
+                 prox_every=4)
+eng = make_engine(prob, cfg, make_task_mesh(2))
+state = eng.init(jnp.zeros((d, t)), jax.random.PRNGKey(0))
+after = eng.run(state, None, 4)
+assert jax.tree.leaves(jax.tree.map(lambda a, b: a.sharding == b.sharding,
+                                    state, after)) == [True] * 9
+eng.run(after, None, 4)
+assert _run_events._cache_size() == 1, _run_events._cache_size()
+"""
+
+
+def test_sharded_init_is_placed_as_run_returns_it():
+    """On a two-device mesh the sharded engine's fresh state sits where
+    `run` leaves it, so the first call compiles the program every later
+    call runs."""
+    import os
+    import subprocess
+    import sys
+
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               XLA_FLAGS="--xla_force_host_platform_device_count=2")
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env["PYTHONPATH"] = os.pathsep.join(
+        [os.path.join(root, "src"), env.get("PYTHONPATH", "")])
+    proc = subprocess.run([sys.executable, "-c", PLACED], env=env, cwd=root,
+                          capture_output=True, text=True, timeout=240)
+    assert proc.returncode == 0, proc.stderr[-3000:]
