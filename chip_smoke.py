@@ -218,6 +218,31 @@ def phase_kernels(seed: int, chk: Checks) -> None:
         chk.close(f"lstsq_grad_sampled n={n} n_t={n_t}", got, want,
                   FLOAT_RTOL)
 
+    # lstsq_grad_sampled_batch: one batch of events over a ragged store at
+    # the benchmark cell's 784 features (not a lane multiple), with
+    # duplicate tasks, an empty task and one of fewer than b rows.
+    d_c = 784
+    counts = rng.integers(0, N_S + 1, T_S).astype(np.int32)
+    counts[:3] = (N_S, 0, BATCH_SIZE - 5)
+    tasks = rng.integers(0, T_S, EVENT_BATCH).astype(np.int32)
+    tasks[:6] = (0, 1, 2, 0, 2, 0)
+    seeds = rng.integers(0, 2**32, EVENT_BATCH, dtype=np.uint32)
+
+    def per_event(xs, ys, ts, ws, ss, rc):
+        def one(_, inp):
+            t, w, s = inp
+            return None, ref.lstsq_grad_sampled_masked_ref(
+                xs[t], w, ys[t], s, BATCH_SIZE, rc[t])
+        return jax.lax.scan(one, None, (ts, ws, ss))[1]
+
+    got, want = both(
+        "lstsq_grad_sampled_batch",
+        lambda xs, ys, ts, ws, ss, rc: ops.lstsq_grad_sampled_batch(
+            xs, ys, ts, ws, ss, batch_size=BATCH_SIZE, row_counts=rc),
+        per_event, f32(T_S, N_S, d_c), f32(T_S, N_S), tasks,
+        f32(EVENT_BATCH, d_c), seeds, counts)
+    chk.close("lstsq_grad_sampled_batch", got, want, FLOAT_RTOL)
+
     # sample_mask: the in-kernel selection bits, uniform and ragged.
     got, want = both(
         "sample_mask", lambda s: ops.sample_mask(N_S, BATCH_SIZE, s),

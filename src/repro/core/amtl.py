@@ -87,9 +87,12 @@ sharded engines replace every forward-step gradient by an unbiased
 simulator's convention).  The per-event sampling seed is folded off the
 main PRNG chain (`_minibatch_seed`), so the (task, staleness) event stream
 — and with batch_size=None the engines' every bit — is unchanged; the
-selection itself is generated in-kernel from counter hashes
-(`repro.kernels.ops.lstsq_grad_sampled`), with no gather and no
-materialized index array.
+selection itself is the rank order of counter hashes.  The delta engine
+generates it in-kernel (`repro.kernels.ops.lstsq_grad_sampled`), with no
+gather and no materialized index array; the batch and sharded engines
+select a whole batch's rows with one sort, gather only those rows and
+contract them in one kernel (`ops.lstsq_grad_sampled_batch`, lstsq with a
+minibatch smaller than the row buffer).
 
 Ragged task cohorts: an `MTLProblem` with `row_counts` set (the
 `repro.data.TaskStore` layout — per-task valid-row counts over a shared
@@ -581,9 +584,9 @@ def _one_batch(problem: MTLProblem, cfg: AMTLConfig, delay_offsets: Array,
 
     # Per-event forward-step gradients at the batch-constant prox.  g_t
     # depends only on (t, p[:, t]) — not on v — so duplicates need no
-    # serialization here; the scan body issues the same per-event ops as
-    # the serial engine, keeping the bits identical.  With batch_size set
-    # each event samples its minibatch from the seed the serial delta
+    # serialization here; the oracle path issues the same per-event ops
+    # as the serial engine, keeping the bits identical.  With batch_size
+    # set each event samples its minibatch from the seed the serial delta
     # engine would derive at that chain position.
     with jax.named_scope("amtl.grad"):
         p_cols = p[:, ts]                                    # (d, bsz)
@@ -595,13 +598,8 @@ def _one_batch(problem: MTLProblem, cfg: AMTLConfig, delay_offsets: Array,
 
             _, g_rows = jax.lax.scan(grad_one, None, (ts, p_cols.T))
         else:
-            def grad_one(_, inp):
-                t, p_t, s = inp
-                return None, problem.task_grad_sampled(t, p_t, s,
-                                                       cfg.batch_size)
-
-            _, g_rows = jax.lax.scan(grad_one, None,
-                                     (ts, p_cols.T, mb_seeds))  # (bsz, d)
+            g_rows = problem.task_grads_sampled(ts, p_cols.T, mb_seeds,
+                                                cfg.batch_size)  # (bsz, d)
 
     with jax.named_scope("amtl.update"):
         # Delay recording / KM relaxation factors, in event order.
@@ -784,13 +782,8 @@ def _one_batch_sharded(problem: MTLProblem, cfg: AMTLConfig,
                 _, g_rows = jax.lax.scan(grad_one, None,
                                          (lts_clamped, p_cols.T))
             else:
-                def grad_one(_, inp):
-                    t_l, p_t, s = inp
-                    return None, problem_l.task_grad_sampled(
-                        t_l, p_t, s, cfg.batch_size)
-
-                _, g_rows = jax.lax.scan(grad_one, None,
-                                         (lts_clamped, p_cols.T, mb_seeds))
+                g_rows = problem_l.task_grads_sampled(
+                    lts_clamped, p_cols.T, mb_seeds, cfg.batch_size)
 
         with jax.named_scope("amtl.update"):
             # Delay recording / KM relaxation in event order; only the
@@ -1036,6 +1029,8 @@ def make_engine(problem: MTLProblem, cfg: AMTLConfig,
     (`make_task_mesh`).  Validation runs here, eagerly — `run` never
     raises on a well-formed event count.
     """
+    from repro.kernels import ops
+
     validate_config(cfg, problem.reg_name)
     if cfg.engine == "dense" and problem.row_counts is not None:
         raise ValueError(
@@ -1046,6 +1041,11 @@ def make_engine(problem: MTLProblem, cfg: AMTLConfig,
     num_tasks = problem.num_tasks
     per_step = cfg.event_batch if cfg.engine in ("batch", "sharded") else 1
     per_refresh = refresh_comm_bytes(cfg, problem.dim, num_tasks, n_shards)
+    # Whether a call's sampled gradients take the batched kernel.
+    grads_batched = (cfg.engine in ("batch", "sharded")
+                     and cfg.batch_size is not None
+                     and problem.sampled_grads_batched(cfg.batch_size)
+                     and ops._on_tpu())
 
     def init(v0: Array, key: Array):
         if cfg.engine == "dense":
@@ -1071,11 +1071,13 @@ def make_engine(problem: MTLProblem, cfg: AMTLConfig,
         if delay_offsets is None:
             delay_offsets = jnp.zeros((num_tasks,), jnp.float32)
         # `comm_bytes`: what the call's refreshes move between the shards
-        # (exact when the call starts on a refresh).
+        # (exact when the call starts on a refresh); `grad_batched_events`:
+        # the call's events whose gradient the batched kernel computed.
         with jax.profiler.TraceAnnotation(
                 "amtl.run", num_events=int(num_events),
                 shards=n_shards or 1,
-                comm_bytes=int(num_events) // cfg.prox_every * per_refresh):
+                comm_bytes=int(num_events) // cfg.prox_every * per_refresh,
+                grad_batched_events=int(num_events) if grads_batched else 0):
             return _run_events(problem, cfg, state, delay_offsets,
                                int(num_events), mesh)
 

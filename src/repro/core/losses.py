@@ -217,6 +217,35 @@ class MTLProblem(NamedTuple):
                  / jnp.maximum(bsz, 1).astype(jnp.float32))
         return scale * get_loss(self.loss_name).grad(x_s, y_t, w_t)
 
+    def sampled_grads_batched(self, batch_size: int) -> bool:
+        """Whether `task_grads_sampled` computes its events in one batched
+        op (`ops.lstsq_grad_sampled_batch`): the lstsq loss, and a
+        minibatch smaller than the row buffer."""
+        return self.loss_name == "lstsq" and batch_size < self.xs.shape[1]
+
+    def task_grads_sampled(self, ts: Array, ws: Array, seeds: Array,
+                           batch_size: int) -> Array:
+        """(B, d): `task_grad_sampled(ts[e], ws[e], seeds[e], batch_size)`
+        of each event e of a batch.
+
+        Where `sampled_grads_batched` holds, one batched op selects,
+        gathers and contracts every event's minibatch at once on a TPU
+        (its oracle path is the per-event scan below, bit for bit);
+        otherwise the events are scanned one by one.
+        """
+        from repro.kernels.ops import lstsq_grad_sampled_batch
+
+        if self.sampled_grads_batched(batch_size):
+            return lstsq_grad_sampled_batch(
+                self.xs, self.ys, ts, ws, seeds, batch_size=batch_size,
+                row_counts=self.row_counts)
+
+        def one(_, inp):
+            t, w_t, seed = inp
+            return None, self.task_grad_sampled(t, w_t, seed, batch_size)
+
+        return jax.lax.scan(one, None, (ts, ws, seeds))[1]
+
     def full_grad(self, w_cols: Array) -> Array:
         """nabla f(W) column-stacked, (d, T) — paper Eq. III.2."""
         loss = get_loss(self.loss_name)

@@ -20,6 +20,13 @@ derived in-kernel from the scalar block — f32 division of integers
 < 2^24, which rounds identically to the uniform path's trace-time
 Python-float constant, so n_t == n keeps the kernel on the same bits.
 
+`lstsq_grad_sampled_batch` is the batch engines' form: a batch's B
+events select their rows with one sort (`ref.sample_rows_batch`, the
+same rank law), XLA gathers just the B x b selected rows, and one kernel
+whose grid is the events contracts each event's (b, d) block.  It reads
+b rows an event where the per-event kernel streams the task's whole
+padded buffer, and runs no per-event loop around its call.
+
 `sample_mask` exposes the kernel's selection bits on their own — the
 hypothesis suite asserts them equal to `ref.sample_mask_ref` /
 `ref.sample_mask_masked_ref` for arbitrary (n, b, seed, n_t), which pins
@@ -35,7 +42,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.lstsq_grad import VMEM_LIMIT, strip_rows
-from repro.kernels.ref import counter_hash, sample_cutoff, sample_cutoff_masked
+from repro.kernels.ref import (counter_hash, sample_cutoff,
+                               sample_cutoff_masked, sample_rows_batch)
 
 Array = jax.Array
 # f32 contractions: on the chip the MXU default is one bf16 pass, which
@@ -149,6 +157,68 @@ def lstsq_grad_sampled(x: Array, w: Array, y: Array, seed: Array, *,
         interpret=interpret,
     )(_scalars(n, batch_size, seed, n_t), x_p, w_p, y_p)
     return out[:d, 0]
+
+
+def _batch_kernel(nt_ref, x_ref, w_ref, y_ref, out_ref, *, b: int,
+                  batch_size: int):
+    e = pl.program_id(0)
+    x = x_ref[...].astype(jnp.float32)          # (b, d) selected rows
+    w = w_ref[...].astype(jnp.float32)          # (1, d)
+    y = y_ref[...].astype(jnp.float32)          # (1, b)
+    r = jax.lax.dot_general(w, x, (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32,
+                            precision=HIGHEST) - y          # (1, b)
+    # Ranks at or past bsz are rows past n_t: their residual is dropped,
+    # as the oracle drops it.  The scale is `_sampled_kernel`'s expression.
+    n_t = nt_ref[e]
+    bsz = jnp.minimum(jnp.int32(batch_size), n_t)
+    rank = jax.lax.broadcasted_iota(jnp.int32, (1, b), 1)
+    r = jnp.where(rank < bsz, r, 0.0)
+    scale2 = 2.0 * (n_t.astype(jnp.float32)
+                    / jnp.maximum(bsz, 1).astype(jnp.float32))
+    out_ref[...] = (scale2 * jnp.dot(
+        r, x, preferred_element_type=jnp.float32,
+        precision=HIGHEST)).astype(out_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("batch_size", "interpret"))
+def lstsq_grad_sampled_batch(xs: Array, ys: Array, ts: Array, ws: Array,
+                             seeds: Array, *, batch_size: int,
+                             row_counts: Array | None = None,
+                             interpret: bool = False) -> Array:
+    """(B, d): event e's (n_t/bsz) * 2 X_S^T (X_S w_e - y_S) on task ts[e].
+
+    xs (T, n, d) and ys (T, n) are the task store, ts (B,) the events'
+    tasks, ws (B, d) their points and seeds (B,) their sampling seeds;
+    `row_counts` (T,) the valid rows per task (None: all n).  The batch's
+    selection is one sort (`ref.sample_rows_batch`), the selected rows
+    one XLA gather of (B, b, d) with b = min(batch_size, n), and the
+    gradients one kernel whose grid is the events: each step reads its
+    event's b rows, not the task's whole buffer.  Same minibatch, scale
+    and HIGHEST contractions as `lstsq_grad_sampled`, event by event.
+    """
+    _, n, d = xs.shape
+    num = ts.shape[0]
+    b = min(batch_size, n)
+    n_ts = (jnp.full((num,), n, jnp.int32) if row_counts is None
+            else row_counts[ts].astype(jnp.int32))
+    rows = sample_rows_batch(n, batch_size, seeds, n_ts)
+    # Blocks span whole trailing dims, so d needs no padding to a lane
+    # multiple (Mosaic pads in VMEM) and the gathered rows are not copied.
+    block = lambda *shape: pl.BlockSpec((None, *shape),
+                                        lambda e, nt: (e, 0, 0))
+    out = pl.pallas_call(
+        functools.partial(_batch_kernel, b=b, batch_size=batch_size),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(num,),
+            in_specs=[block(b, d), block(1, d), block(1, b)],
+            out_specs=block(1, d)),
+        out_shape=jax.ShapeDtypeStruct((num, 1, d), ws.dtype),
+        interpret=interpret,
+        name="lstsq_grad_sampled",
+    )(n_ts, xs[ts[:, None], rows], ws.reshape(num, 1, d),
+      ys[ts[:, None], rows].reshape(num, 1, b))
+    return out[:, 0]
 
 
 def _mask_kernel(scal_ref, out_ref, *, bn: int):

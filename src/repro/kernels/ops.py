@@ -26,6 +26,8 @@ from repro.kernels.l21_prox import l21_prox as _l21_pallas
 from repro.kernels.lstsq_grad import lstsq_grad as _lstsq_pallas
 from repro.kernels.lstsq_grad_sampled import \
     lstsq_grad_sampled as _lstsq_sampled_pallas
+from repro.kernels.lstsq_grad_sampled import \
+    lstsq_grad_sampled_batch as _lstsq_sampled_batch_pallas
 from repro.kernels.lstsq_grad_sampled import sample_mask as _sample_mask_pallas
 from repro.kernels.svt_reconstruct import \
     svt_reconstruct as _svt_reconstruct_pallas
@@ -177,6 +179,43 @@ def lstsq_grad_sampled(x: Array, w: Array, y: Array, seed: Array, *,
     if n_t is None:
         return ref.lstsq_grad_sampled_ref(x, w, y, seed, batch_size)
     return ref.lstsq_grad_sampled_masked_ref(x, w, y, seed, batch_size, n_t)
+
+
+@functools.partial(jax.jit, static_argnames=("batch_size", "use_pallas",
+                                             "interpret"))
+def lstsq_grad_sampled_batch(xs: Array, ys: Array, ts: Array, ws: Array,
+                             seeds: Array, *, batch_size: int,
+                             row_counts: Array | None = None,
+                             use_pallas: bool | None = None,
+                             interpret: bool = False) -> Array:
+    """(B, d) seeded-minibatch gradients of a batch of events, one pass.
+
+    Event e's row is `lstsq_grad_sampled` of task ts[e]'s rows of the
+    store xs (T, n, d), ys (T, n) at ws[e] with seed seeds[e] and n_t =
+    row_counts[ts[e]] (n where row_counts is None).  On a TPU the batch's
+    selection is one sort, its rows one gather and its gradients one
+    Pallas call; the oracle path scans the events through
+    `lstsq_grad_sampled`, bit for bit the per-event engine step.
+    """
+    if use_pallas is None:
+        use_pallas = _on_tpu()
+    if use_pallas or interpret:
+        return _lstsq_sampled_batch_pallas(xs, ys, ts, ws, seeds,
+                                           batch_size=batch_size,
+                                           row_counts=row_counts,
+                                           interpret=interpret)
+
+    def one(_, inp):
+        t, w, seed = inp
+        x_t = jax.lax.dynamic_index_in_dim(xs, t, axis=0, keepdims=False)
+        y_t = jax.lax.dynamic_index_in_dim(ys, t, axis=0, keepdims=False)
+        n_t = None if row_counts is None else jax.lax.dynamic_index_in_dim(
+            row_counts, t, axis=0, keepdims=False)
+        return None, lstsq_grad_sampled(x_t, w, y_t, seed,
+                                        batch_size=batch_size, n_t=n_t,
+                                        use_pallas=False)
+
+    return jax.lax.scan(one, None, (ts, ws, seeds))[1]
 
 
 @functools.partial(jax.jit, static_argnames=("n", "batch_size", "use_pallas",

@@ -210,9 +210,11 @@ def sample_cutoff(n: int, batch_size: int, seed: Array) -> tuple[Array, Array]:
     per-row local predicate, which is how the Pallas kernel re-derives
     the selection in VMEM from just these two scalars.  batch_size >= n
     saturates the cutoff (every real row kept): the clamp that makes the
-    saturated path degrade to the full gradient.  O(n log n) uint32 sort
-    per event — noise next to the O(n d) (full) or O(bsz d) (sampled)
-    gradient contraction it steers.
+    saturated path degrade to the full gradient.  One O(n log n) uint32
+    sort per event: not noise on a TPU, where at n = 512 it took 4.2 µs
+    an event, more than half the per-event kernel it steered (TPU v5e,
+    batch engine).  The batch and sharded engines there sort a whole
+    batch's hashes at once instead (`sample_rows_batch`).
     """
     bsz = min(batch_size, n)
     if bsz >= n:
@@ -292,6 +294,27 @@ def sample_mask_masked_ref(n: int, batch_size: int, seed: Array,
     h = counter_hash(seed, idx)
     keep = (h < cut_h) | ((h == cut_h) & (idx <= cut_i))
     return keep & (idx < n_t.astype(jnp.uint32))
+
+
+def sample_rows_batch(n: int, batch_size: int, seeds: Array,
+                      n_t: Array) -> Array:
+    """(B, min(batch_size, n)) int32: each event's rows in rank order.
+
+    Row e ranks the n rows of a padded buffer by (invalid, hash, row)
+    under event e's seed `seeds[e]` and valid-row count `n_t[e]`, all B
+    events in one sort.  Its first min(batch_size, n_t[e]) entries are
+    exactly `sample_mask_masked_ref`'s set (`sample_mask_ref`'s where n_t
+    is n); later entries are rows past n_t, which a caller masks out.  An
+    invalid row takes the largest hash: a valid row that ties it has the
+    smaller index, so the sort needs only the keys (hash, row).
+    """
+    rows = jnp.arange(n, dtype=jnp.uint32)
+    h = counter_hash(seeds.astype(jnp.uint32)[:, None], rows[None, :])
+    valid = rows[None, :] < n_t.astype(jnp.uint32)[:, None]
+    key = jnp.where(valid, h, jnp.uint32(0xFFFFFFFF))
+    idx = jnp.broadcast_to(rows.astype(jnp.int32), key.shape)
+    _, order = jax.lax.sort((key, idx), dimension=1, num_keys=2)
+    return order[:, :min(batch_size, n)]
 
 
 def lstsq_grad_sampled_masked_ref(x: Array, w: Array, y: Array, seed: Array,

@@ -343,7 +343,9 @@ def test_run_opens_a_host_span_with_its_event_count(small_problem, tmp_path):
     data = ProfileData.from_file(str(next(tmp_path.rglob("*.xplane.pb"))))
     runs = [dict(ev.stats) for plane in data.planes for line in plane.lines
             for ev in line.events if ev.name == "amtl.run"]
-    assert runs == [{"num_events": 8, "shards": 1, "comm_bytes": 0}]
+    # On the CPU no gradient takes the batched (TPU) kernel.
+    assert runs == [{"num_events": 8, "shards": 1, "comm_bytes": 0,
+                     "grad_batched_events": 0}]
 
 
 

@@ -232,6 +232,38 @@ def test_lstsq_grad_sampled_property(n, d, bsz, seed):
     np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-4)
 
 
+@pytest.mark.parametrize("ragged", (False, True), ids=("uniform", "ragged"))
+@pytest.mark.parametrize("t,n,d,bsz", [(6, 40, 12, 8), (5, 520, 300, 32),
+                                       (4, 64, 784, 32), (3, 9, 130, 8)])
+def test_lstsq_grad_sampled_batch_matches_per_event_oracle(t, n, d, bsz,
+                                                           ragged):
+    """The batched kernel (interpret mode) against the per-event oracle,
+    event by event: duplicate tasks, and events clamped onto task 0 as the
+    sharded engine clamps another shard's events; ragged cohorts from
+    empty through fewer than bsz rows to full."""
+    from repro.kernels.lstsq_grad_sampled import lstsq_grad_sampled_batch
+    kx, ky, kw, ks, kc = jax.random.split(jax.random.PRNGKey(n + d), 5)
+    xs = jax.random.normal(kx, (t, n, d), jnp.float32) / np.sqrt(d)
+    ys = jax.random.normal(ky, (t, n), jnp.float32)
+    ts = jnp.asarray([0, t - 1, t - 1, 0, 1, 0, 2, 0], jnp.int32)
+    ws = jax.random.normal(kw, (ts.shape[0], d), jnp.float32)
+    seeds = jax.random.bits(ks, ts.shape, jnp.uint32)
+    counts = None
+    if ragged:
+        counts = jax.random.randint(kc, (t,), 0, n + 1).at[:3].set(
+            jnp.asarray([n, 0, min(bsz - 1, n)]))
+    got = lstsq_grad_sampled_batch(xs, ys, ts, ws, seeds, batch_size=bsz,
+                                   row_counts=counts, interpret=True)
+    want = jnp.stack([
+        ref.lstsq_grad_sampled_ref(xs[k], ws[e], ys[k], seeds[e], bsz)
+        if counts is None else
+        ref.lstsq_grad_sampled_masked_ref(xs[k], ws[e], ys[k], seeds[e], bsz,
+                                          counts[k])
+        for e, k in enumerate(np.asarray(ts))])
+    scale = float(jnp.max(jnp.abs(want)))
+    np.testing.assert_allclose(got, want, rtol=1e-6, atol=1e-6 * scale)
+
+
 @settings(max_examples=25, deadline=None)
 @given(st.integers(1, 1100), st.integers(1, 1100), st.integers(0, 2**32 - 1))
 def test_sample_mask_kernel_bitwise(n, bsz, seed):

@@ -331,6 +331,78 @@ def test_masked_cutoff_keeps_exactly_min_b_nt_valid_rows(setup):
             want, np.asarray(ref.sample_mask_ref(n, b, seed_j)))
 
 
+@st.composite
+def _batch_selection_setups(draw):
+    n = draw(st.integers(2, 600))           # crosses a 512-row strip
+    b = draw(st.integers(1, n - 1))
+    # every cohort edge: empty, one row, fewer than b, exactly b, full
+    counts = draw(st.lists(
+        st.sampled_from([0, 1, max(b - 1, 0), b, n]) | st.integers(0, n),
+        min_size=1, max_size=5))
+    ts = draw(st.lists(st.integers(0, len(counts) - 1), min_size=1,
+                       max_size=8))         # duplicate tasks are common
+    seeds = draw(st.lists(st.integers(0, 2**32 - 1), min_size=len(ts),
+                          max_size=len(ts)))
+    return n, b, counts, ts, seeds
+
+
+@settings(max_examples=40, deadline=None)
+@given(_batch_selection_setups())
+def test_batched_selection_is_each_events_oracle_set(setup):
+    """One sort for the whole batch picks, for every event, exactly the
+    rows `sample_mask_masked_ref` keeps (and `sample_mask_ref` where the
+    task is full); its ranks past min(b, n_t) hold only rows past n_t."""
+    n, b, counts, ts, seeds = setup
+    n_ts = jnp.asarray(counts, jnp.int32)[jnp.asarray(ts)]
+    seeds_j = jnp.asarray(seeds, jnp.uint32)
+    rows = np.asarray(jax.jit(ref.sample_rows_batch, static_argnums=(0, 1))(
+        n, b, seeds_j, n_ts))
+    assert rows.shape == (len(ts), b)
+    for e, t in enumerate(ts):
+        n_t, bsz = counts[t], min(b, counts[t])
+        want = np.asarray(ref.sample_mask_masked_ref(
+            n, b, seeds_j[e], jnp.asarray(n_t, jnp.int32)))
+        assert len(set(rows[e])) == b
+        assert set(rows[e, :bsz]) == set(np.flatnonzero(want))
+        assert (rows[e, bsz:] >= n_t).all()
+        if n_t == n:
+            full = np.asarray(ref.sample_mask_ref(n, b, seeds_j[e]))
+            assert set(rows[e]) == set(np.flatnonzero(full))
+
+
+@pytest.mark.parametrize("ragged", (False, True), ids=("uniform", "ragged"))
+def test_batched_gradients_cpu_dispatch_is_the_engine_scan_bitwise(ragged):
+    """On the oracle path `ops.lstsq_grad_sampled_batch`, and through it
+    `MTLProblem.task_grads_sampled`, give the per-event scan's bits: the
+    batch and sharded engines' CPU contracts rest on it.  The batch holds
+    duplicate tasks and the sharded engine's clamped foreign events."""
+    t, n, d, b = 5, 40, 12, 8
+    kx, ky, kw, ks = jax.random.split(jax.random.PRNGKey(11), 4)
+    counts = jnp.asarray([40, 0, 3, 8, 17], jnp.int32) if ragged else None
+    problem = MTLProblem(jax.random.normal(kx, (t, n, d)) / np.sqrt(d),
+                         jax.random.normal(ky, (t, n)), "lstsq", "nuclear",
+                         0.1, counts)
+    ts = jnp.asarray([0, 3, 3, 0, 4, 1, 2, 0], jnp.int32)
+    ws = jax.random.normal(kw, (ts.shape[0], d))
+    seeds = jax.random.bits(ks, ts.shape, jnp.uint32)
+
+    @jax.jit
+    def scan(problem, ts, ws, seeds):
+        def one(_, inp):
+            return None, problem.task_grad_sampled(*inp, b)
+        return jax.lax.scan(one, None, (ts, ws, seeds))[1]
+
+    want = np.asarray(scan(problem, ts, ws, seeds))
+    got = jax.jit(lambda p, *a: ops.lstsq_grad_sampled_batch(
+        p.xs, p.ys, *a, batch_size=b, row_counts=p.row_counts,
+        use_pallas=False))(problem, ts, ws, seeds)
+    np.testing.assert_array_equal(np.asarray(got), want)
+    assert problem.sampled_grads_batched(b)
+    got = jax.jit(lambda p, *a: p.task_grads_sampled(*a, b))(
+        problem, ts, ws, seeds)
+    np.testing.assert_array_equal(np.asarray(got), want)
+
+
 @settings(max_examples=25, deadline=None)
 @given(st.integers(0, 2**32 - 1), st.integers(1, 40), st.integers(0, 40))
 def test_masked_grad_matches_trimmed_dense_grad(seed, n, n_t_raw):

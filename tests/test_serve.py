@@ -423,6 +423,7 @@ def test_a_chunk_boundary_opens_its_spans_with_their_counts(small_problem,
         "rows": 8, "bytes": store.xs.nbytes + store.ys.nbytes
         + store.row_counts.nbytes}
     assert spans["amtl.run"][2] == {"num_events": 8, "shards": 1,
-                                    "comm_bytes": 0}
+                                    "comm_bytes": 0,
+                                    "grad_batched_events": 0}
     assert spans["serve.predict"][2] == {"rows": 5}
     assert not (lo <= spans["serve.predict"][0] < hi)

@@ -17,6 +17,7 @@ is reused for the chip and no chip-traced program leaks into later CPU
 tests of the same worker.
 """
 import os
+import re
 
 import pytest
 import jax
@@ -73,10 +74,66 @@ def _assert_pallas(compiled):
         "compiled program took the jnp oracle, not the Pallas kernel"
 
 
+_COMPUTATION = re.compile(r"^(?:ENTRY )?%([\w.\-]+) \(")
+
+
+def _callees(line: str) -> list[str]:
+    names = re.findall(r"(?:body|calls|to_apply|condition)=%([\w.\-]+)", line)
+    for group in re.findall(r"branch_computations=\{([^}]*)\}", line):
+        names += re.findall(r"%([\w.\-]+)", group)
+    return names
+
+
+def _loops_holding(text: str, kernel: str) -> list[str]:
+    """op_names of the compiled program's while loops whose body runs the
+    Pallas call named `kernel.N`, directly or in a computation it calls."""
+    comps, name = {}, None
+    for line in text.splitlines():
+        m = _COMPUTATION.match(line)
+        if m:
+            name = m.group(1)
+            comps[name] = []
+        elif name is not None:
+            comps[name].append(line)
+    call = re.compile(rf"%{re.escape(kernel)}\.\d+ = .*tpu_custom_call")
+    holds = {c for c, lines in comps.items()
+             if any(call.search(line) for line in lines)}
+
+    def runs_kernel(c, seen):
+        if c in seen:
+            return False
+        seen.add(c)
+        return c in holds or any(runs_kernel(d, seen)
+                                 for line in comps.get(c, ())
+                                 for d in _callees(line))
+
+    loops = []
+    for lines in comps.values():
+        for line in lines:
+            m = re.search(r" while\(.*body=%([\w.\-]+)", line)
+            if m and runs_kernel(m.group(1), set()):
+                loops.append(re.search(r'op_name="([^"]*)"', line).group(1))
+    return loops
+
+
+BATCH_LOOP = "jit(_run_events)/while"
+
+
+def _assert_gradients_batched(text: str, store_shape) -> None:
+    """One `lstsq_grad_sampled.N` Pallas call a batch: no loop over the
+    events holds it (the batch loop of `_run_events` alone does), and the
+    program copies the (t, n, d) task store at most once."""
+    assert len(re.findall(r"%lstsq_grad_sampled\.\d+ = .*tpu_custom_call",
+                          text)) == 1
+    assert set(_loops_holding(text, "lstsq_grad_sampled")) == {BATCH_LOOP}
+    store = "f32[{},{},{}]".format(*store_shape)
+    assert len(re.findall(rf" = {re.escape(store)}\S* copy\(", text)) <= 1
+
+
 KERNELS = ("amtl_event_batch", "lstsq_grad_sampled",
            "lstsq_grad_sampled_grown", "lstsq_grad_sampled_wide",
            "sample_mask", "gauss_sketch", "gauss_sketch_shard",
-           "svt_reconstruct")
+           "svt_reconstruct", "lstsq_grad_sampled_batch")
 
 
 @pytest.mark.parametrize("name", KERNELS)
@@ -88,6 +145,13 @@ def test_kernel_compiles_for_v5e(one_chip, name):
         lowered = ops.amtl_event_batch.lower(
             s((D, T)), s((D, EVENT_BATCH)), s((D, EVENT_BATCH)),
             s((EVENT_BATCH,), jnp.int32), s(()), s((EVENT_BATCH,)),
+            use_pallas=True)
+    elif name == "lstsq_grad_sampled_batch":
+        # a batch of the benchmark cell's events over its whole store
+        lowered = ops.lstsq_grad_sampled_batch.lower(
+            s((T_C, N_C, D_C)), s((T_C, N_C)), s((EVENT_BATCH,), jnp.int32),
+            s((EVENT_BATCH, D_C)), s((EVENT_BATCH,), jnp.uint32),
+            batch_size=BATCH_SIZE, row_counts=s((T_C,), jnp.int32),
             use_pallas=True)
     elif name.startswith("lstsq_grad_sampled"):
         # the serve store after its first doubling; the engine's width
@@ -126,18 +190,23 @@ def _engine_args(sharding, d, t, n, *, ragged):
     return problem, cfg, jax.tree.map(on_chip, state), _spec(sharding, (t,))
 
 
-@pytest.mark.parametrize("shape", ("engine", "gradient", "serve"))
+@pytest.mark.parametrize("shape", ("engine", "gradient", "serve", "cell"))
 def test_batch_engine_step_compiles_for_v5e(one_chip, monkeypatch, shape):
     """The whole `_run_events` program (randomized prox, sampled grads,
-    batched column update) with the dispatch steered to Pallas in-test."""
+    batched column update) with the dispatch steered to Pallas in-test.
+    Where it samples minibatches (all but `engine`), a batch's gradients
+    are one Pallas call; `cell` is the benchmark cell's step (3400 ragged
+    writers of 512 rows at 784 features), which compiles in about 20 s."""
     d, t, n = {"engine": (D, T, N), "gradient": (D_G, T_G, N_G),
-               "serve": (D_S, T_S, 2 * N_S)}[shape]
+               "serve": (D_S, T_S, 2 * N_S), "cell": (D_C, T_C, N_C)}[shape]
     monkeypatch.setattr(ops, "_on_tpu", lambda: True)
-    problem, cfg, state, offs = _engine_args(one_chip, d, t, n,
-                                             ragged=shape == "serve")
+    problem, cfg, state, offs = _engine_args(
+        one_chip, d, t, n, ragged=shape in ("serve", "cell"))
     compiled = _run_events.lower(problem, cfg, state, offs,
                                  2 * EVENT_BATCH).compile()
     _assert_pallas(compiled)
+    if cfg.batch_size is not None:
+        _assert_gradients_batched(compiled.as_text(), (t, n, d))
 
 
 @pytest.mark.parametrize("prox_mode,cell", (("replicated", False),
@@ -187,6 +256,7 @@ def test_sharded_engine_step_compiles_for_v5e_2x2(topo, monkeypatch,
         store = t * n * d * 4
         args = compiled.memory_analysis().argument_size_in_bytes
         assert store / n_shards <= args < store / n_shards + 8 * d * t * 4
+        _assert_gradients_batched(text, (t // n_shards, n, d))
 
 
 PHASES = ("amtl.sample", "amtl.prox", "amtl.grad", "amtl.update")
