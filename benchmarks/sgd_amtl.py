@@ -26,7 +26,8 @@ from repro.core import NetworkModel, make_synthetic, simulate_amtl
 EPOCHS = 10
 SAMPLES = 200
 
-# engine-backed row: large-n stacked problem, jitted delta engine
+# engine-backed row: large-n stacked problem, jitted batch engine, one
+# event a step
 E_TASKS, E_SAMPLES, E_DIM, E_BSZ, E_EVENTS = 8, 512, 1024, 32, 256
 
 
@@ -43,7 +44,7 @@ def _engine_rows() -> list[Row]:
     problem = MTLProblem(xs, ys, "lstsq", "nuclear", 0.1)
     cfg_full = AMTLConfig(eta=1.0 / problem.lipschitz(),
                           eta_k=amtl_max_step(4, E_TASKS), tau=4,
-                          engine="delta", prox_every=8, prox_rank=8)
+                          engine="batch", prox_every=8, prox_rank=8)
     cfg_sgd = cfg_full._replace(batch_size=E_BSZ)
     w0 = jnp.zeros((E_DIM, E_TASKS), jnp.float32)
     key = jax.random.PRNGKey(7)

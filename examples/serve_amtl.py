@@ -50,7 +50,7 @@ def main():
     problem = make_mtl_problem(num_tasks=6, samples=40, dim=32, rank=2,
                                lam=0.1, seed=0)
     cfg = AMTLConfig(eta=1.0 / problem.lipschitz(), eta_k=0.9, tau=4,
-                     engine="delta", prox_every=4, prox_rank=4)
+                     engine="batch", prox_every=4, prox_rank=4)
     w0 = jax.numpy.zeros((problem.dim, problem.num_tasks), jax.numpy.float32)
     key = jax.random.PRNGKey(0)
     t, x, fb = _traffic(problem)

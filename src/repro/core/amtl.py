@@ -13,46 +13,38 @@ simulation* inside `lax.scan`/`fori_loop`:
             (Eq. III.4), optionally scaled by the delay-adaptive multiplier
             (Eq. III.5/III.6).
 
-Four engines implement the same mathematics:
+Three engines implement the same mathematics:
 
-  engine="delta" (default) — the delta ring.  Only ONE full iterate V is kept;
-      each event appends `(task_id, pre-write column)` to a `(tau+1, d)` undo
-      log, and the stale read at staleness nu is reconstructed lazily by
-      rolling back the nu newest log entries (O(tau*d) work, O(tau*d) memory).
-      Per-event state writes are O(d): one column of V plus one ring slot.
-      The fused column math (forward step + KM relaxation + undo-log emit)
-      is the `amtl_event` kernel (`repro.kernels.ops.amtl_event`).
-      The server-side prox can be amortized (`prox_every` — paper §III-C:
-      "the proximal mapping can be also applied after several gradient
-      updates"), with `svt_randomized` as the refresh for the nuclear norm
-      at large d x T (`prox_rank`).
+  engine="batch" (default) — the delta ring, `event_batch` events per loop
+      step (default 1).  Only ONE full iterate V is kept; each event
+      appends `(task_id, pre-write column)` to a `(tau+1, d)` undo log,
+      and the stale read at staleness nu is reconstructed lazily by
+      rolling back the nu newest log entries (O(tau*d) work and memory).
+      Each step replays `event_batch` draws of the serial PRNG chain, so
+      the (task, staleness) event stream does not depend on
+      `event_batch`; refreshes the server prox only at batch boundaries;
+      and applies all column updates through `ops.amtl_event_batch`
+      (gather -> fused forward/KM/undo-emit -> scatter).  Within-batch
+      conflicts — duplicate tasks — are serialized in event order: a
+      later event reads the column as left by the earlier in-batch write,
+      and its undo-log entry records that pre-write column, so the ring
+      replays exactly as if the events had been applied one at a time.
+      The server prox can be amortized (`prox_every` — paper §III-C: "the
+      proximal mapping can be also applied after several gradient
+      updates"), with `svt_randomized` as the refresh for the nuclear
+      norm at large d x T (`prox_rank`).  `prox_every = k * event_batch`
+      refreshes the prox at every k-th batch's first event and carries
+      the result in a (d, T) prox cache between batches (k == 1 refreshes
+      every batch and carries no cache).  At the same `prox_every` and
+      key, any `event_batch` reproduces the `event_batch=1` iterates
+      bitwise on the CPU oracle path.
 
-  engine="dense" — the seed engine: a `(tau+1, d, T)` ring of full iterates,
-      O(d*T) HBM writes per event.  Kept as the equivalence baseline; the
-      delta engine reproduces its iterates bitwise under the same PRNG key
-      when `prox_every == 1` and both engines run the same arithmetic
-      dispatch (the CPU oracle path, where `ops.amtl_event` lowers to the
-      same jnp expression as `km_block_update`; on TPU the Pallas kernel
-      may contract FMAs differently, so expect ulp-level, not bitwise,
-      agreement there).
-
-  engine="batch" — the delta ring, `event_batch` events per loop step.
-      Each step replays `event_batch` draws of the serial PRNG chain (so
-      the (task, staleness) event stream is identical to the one-event
-      engines by construction), refreshes the server prox only at batch
-      boundaries, and applies all column updates through
-      `ops.amtl_event_batch` (gather -> fused forward/KM/undo-emit ->
-      scatter).  Within-batch conflicts — duplicate tasks — are serialized
-      in event order: a later event reads the column as left by the
-      earlier in-batch write, and its undo-log entry records that
-      pre-write column, so the ring replays exactly as if the events had
-      been applied one at a time.  The prox cadence is decoupled from the
-      batch size: `prox_every = k * event_batch` refreshes the prox at
-      every k-th batch's first event and carries the result in a (d, T)
-      prox cache between batches (k == 1 refreshes every batch and carries
-      no cache).  For matched cadences (same `prox_every`, same key) the
-      batch engine reproduces the delta engine's iterates bitwise on the
-      CPU oracle path.
+  engine="dense" — the seed engine: a `(tau+1, d, T)` ring of full
+      iterates, O(d*T) HBM writes per event, one event per step.  Kept as
+      the exact reference: the batch engine at `prox_every == 1`
+      reproduces its iterates bitwise under the same PRNG key on the CPU
+      oracle path (on TPU the Pallas kernels may contract FMAs
+      differently, so expect ulp-level, not bitwise, agreement there).
 
   engine="sharded" — the batch engine with the T task columns partitioned
       over a 1-D "tasks" mesh axis (shard_map).  Each shard owns a (d,
@@ -80,28 +72,31 @@ Four engines implement the same mathematics:
       bitwise on the CPU oracle path, and per-shard `delay_offsets` skews
       model the paper's slow-node regime (a lagging shard's tasks read at
       high staleness without stalling the other shards' event stream).
+      The two share their prox cadence, event gradients and ring append
+      (`_prox_cadence`, `_event_grads`, `_ring_append`); the stale read,
+      ownership masking and collectives are the sharded step's own.
 
-SGD-AMTL (paper §V): with `AMTLConfig(batch_size=b)` the delta, batch, and
+SGD-AMTL (paper §V): with `AMTLConfig(batch_size=b)` the batch and
 sharded engines replace every forward-step gradient by an unbiased
 (n_t/bsz)-scaled seeded minibatch gradient (bsz = min(b, n_t), the
 simulator's convention).  The per-event sampling seed is folded off the
 main PRNG chain (`_minibatch_seed`), so the (task, staleness) event stream
 — and with batch_size=None the engines' every bit — is unchanged; the
-selection itself is the rank order of counter hashes.  The delta engine
-generates it in-kernel (`repro.kernels.ops.lstsq_grad_sampled`), with no
-gather and no materialized index array; the batch and sharded engines
-select a whole batch's rows with one sort, gather only those rows and
-contract them in one kernel (`ops.lstsq_grad_sampled_batch`, lstsq with a
-minibatch smaller than the row buffer).
+selection itself is the rank order of counter hashes.  Where the
+minibatch is smaller than the row buffer (lstsq), a batch's rows are
+selected with one sort, gathered and contracted in one kernel
+(`ops.lstsq_grad_sampled_batch`); otherwise each event's gradient is the
+per-event `ops.lstsq_grad_sampled` (or the masked jnp logistic
+gradient), scanned over the batch.
 
 Ragged task cohorts: an `MTLProblem` with `row_counts` set (the
 `repro.data.TaskStore` layout — per-task valid-row counts over a shared
-padded buffer) runs unchanged through the delta, batch, and sharded
-engines; every loss/gradient/minibatch expression masks rows >= n_t
-inside `repro.core.losses`, the sharded engine ships row_counts as one
-more per_task shard_map input, and uniform row_counts reproduce the
-unmasked engines bitwise on the CPU oracle path.  engine="dense" is the
-exact uniform seed baseline and rejects ragged problems.
+padded buffer) runs unchanged through the batch and sharded engines;
+every loss/gradient/minibatch expression masks rows >= n_t inside
+`repro.core.losses`, the sharded engine ships row_counts as one more
+per_task shard_map input, and uniform row_counts reproduce the unmasked
+engines bitwise on the CPU oracle path.  engine="dense" is the exact
+uniform seed baseline and rejects ragged problems.
 
 This is bit-faithful to Algorithm 1's mathematics while being jit-compiled,
 deterministic under a PRNG key, and mesh-shardable.  Wall-clock behaviour
@@ -138,7 +133,7 @@ from repro.core.dynamic_step import DelayHistory, dynamic_multiplier
 from repro.core.losses import MTLProblem
 from repro.core.operators import (amtl_max_step, backward,
                                   fixed_point_residual, km_block_update,
-                                  rollback_columns, rollback_columns_batch,
+                                  rollback_columns_batch,
                                   rollback_columns_shard)
 from repro.core.prox import ProxPlan, svt_randomized, svt_randomized_dist
 from repro.distributed.sharding import (TASK_AXIS, prox_cache_spec,
@@ -161,24 +156,25 @@ class AMTLConfig(NamedTuple):
     # Per-task mean staleness (in events). The sampled delay is
     # min(round(offset_t + U[0,1) * jitter), tau). offsets=None => all zero.
     delay_jitter: float = 1.0
-    # "delta": O(d) per-event state with an undo-log ring (default).
-    # "dense": the seed (tau+1, d, T) full-iterate ring, for equivalence.
-    # "batch": the delta ring, event_batch events per loop step with
-    #          batch-boundary prox refreshes and conflict-aware updates.
+    # "batch": one iterate plus an O(tau*d) undo-log ring, event_batch
+    #          events per loop step with batch-boundary prox refreshes and
+    #          conflict-aware updates (default).
     # "sharded": the batch engine with task columns partitioned over a
-    #          "tasks" mesh axis; one all_gather per prox refresh.
-    engine: str = "delta"
+    #          "tasks" mesh axis; collectives only at prox refreshes.
+    # "dense": the seed (tau+1, d, T) full-iterate ring, the exact
+    #          reference the tests compare against.
+    engine: str = "batch"
     # Server prox amortization (paper §III-C): refresh the backward step
     # every K events, reuse the cached prox in between.  K=1 == exact AMTL.
-    # For engine="batch"/"sharded" K must be a multiple of event_batch
-    # (refreshes happen at batch boundaries); K = k*event_batch with k > 1
-    # carries the refreshed prox in a (d, T) cache across batches — the
-    # sharded engine then pays its all_gather only every k batches.
+    # K must be a multiple of event_batch (refreshes happen at batch
+    # boundaries); K = k*event_batch with k > 1 carries the refreshed prox
+    # in a (d, T) cache across batches — the sharded engine then pays its
+    # collectives only every k batches.
     prox_every: int = 1
     # If set (nuclear reg only), prox refreshes use the randomized SVT
     # sketch at this rank instead of the dense SVD — the large-d*T regime.
     prox_rank: int | None = None
-    # engine="batch"/"sharded" only: activations applied per loop step.
+    # Activations applied per loop step (engine="dense" takes only 1).
     event_batch: int = 1
     # engine="sharded" only: how the server prox is executed at a refresh.
     # "replicated": ONE all_gather assembles the (d, T) stale iterate and
@@ -200,8 +196,7 @@ class AMTLConfig(NamedTuple):
     # is untouched and every shard of the sharded engine re-derives the
     # identical seed, sampling shard-locally.  None = exact full
     # gradients, bitwise-identical to the pre-SGD engines.  Supported by
-    # the delta, batch, and sharded engines (dense is the exact seed
-    # baseline).
+    # the batch and sharded engines (dense is the exact seed baseline).
     batch_size: int | None = None
 
 
@@ -214,28 +209,16 @@ class AMTLState(NamedTuple):
     key: Array             # PRNG
 
 
-class DeltaAMTLState(NamedTuple):
-    """Delta-engine state: one iterate + an O(tau*d) undo log."""
-    v: Array               # (d, T) current iterate (the only full copy)
-    delta_ring: Array      # (tau+1, d) pre-write column per event (undo log)
-    task_ring: Array       # (tau+1,) int32 task written at each event
-    ptr: Array             # int32 slot of the newest event
-    event: Array           # int32 global event counter
-    p_cache: Array         # (d, T) cached server prox (prox_every > 1)
-    history: DelayHistory
-    key: Array
-
-
 class BatchAMTLState(NamedTuple):
-    """Batch-engine state: the delta ring with a per-cadence prox cache.
+    """Batch-engine state: one iterate, an O(tau*d) undo log (the delta
+    ring) and a per-cadence prox cache.
 
     At the aligned cadence (prox_every == event_batch) the prox is
     refreshed unconditionally at each batch's first event, so no (d, T)
-    cache is carried between loop steps (`p_cache` stays a (0, 0) stub) —
-    the per-event `lax.cond` copy of that cache is the delta engine's
-    dominant non-prox cost.  With the decoupled cadence (prox_every =
-    k*event_batch, k > 1) `p_cache` holds the last refreshed prox and is
-    reused by the k-1 batches between refreshes.
+    cache is carried between loop steps (`p_cache` stays a (0, 0) stub).
+    With the decoupled cadence (prox_every = k*event_batch, k > 1)
+    `p_cache` holds the last refreshed prox and is reused by the k-1
+    batches between refreshes, behind one `lax.cond` a batch.
     """
     v: Array               # (d, T) current iterate (the only full copy)
     delta_ring: Array      # (tau+1, d) pre-write column per event (undo log)
@@ -285,31 +268,15 @@ def init_state(cfg: AMTLConfig, v0: Array, num_tasks: int,
     )
 
 
-def init_delta_state(cfg: AMTLConfig, v0: Array, num_tasks: int,
-                     key: Array) -> DeltaAMTLState:
-    depth = cfg.tau + 1
-    return DeltaAMTLState(
-        v=v0,
-        delta_ring=jnp.zeros((depth, v0.shape[0]), v0.dtype),
-        task_ring=jnp.zeros((depth,), jnp.int32),
-        ptr=jnp.zeros((), jnp.int32),
-        event=jnp.zeros((), jnp.int32),
-        p_cache=_prox_cache_init(cfg, v0),
-        history=DelayHistory.create(num_tasks, cfg.delay_window),
-        key=key,
-    )
-
-
 def _prox_cache_init(cfg: AMTLConfig, v0: Array) -> Array:
     """(d, T) zeros when a cache is actually carried, else a (0, 0) stub.
 
-    The aligned cadence (prox_every <= event_batch for the batch engines,
-    prox_every == 1 for delta) refreshes before every read and never
-    consults the cache, so no dead (d, T) buffer rides the loop carry;
-    with amortization, event 0 always refreshes before the first read.
+    The aligned cadence (prox_every <= event_batch) refreshes before every
+    read and never consults the cache, so no dead (d, T) buffer rides the
+    loop carry; with amortization, event 0 always refreshes before the
+    first read.
     """
-    carried = cfg.prox_every > (cfg.event_batch
-                                if cfg.engine in ("batch", "sharded") else 1)
+    carried = cfg.prox_every > cfg.event_batch
     return jnp.zeros_like(v0) if carried else jnp.zeros((0, 0), v0.dtype)
 
 
@@ -345,11 +312,9 @@ def init_sharded_state(cfg: AMTLConfig, v0: Array, num_tasks: int,
 
 def _sample_activation(cfg: AMTLConfig, delay_offsets: Array, key: Array,
                        num_tasks: int, event: Array):
-    """Shared event sampling: (next key, activated task, staleness nu).
-
-    Identical PRNG consumption in both engines => bitwise-reproducible
-    event sequences across `engine=` choices.
-    """
+    """The dense engine's event sampling: (next key, activated task,
+    staleness nu).  One step of the chain `_sample_activation_batch`
+    unrolls, so every engine draws the same event sequence."""
     key, k_task, k_delay = jax.random.split(key, 3)
     t, nu = _event_draw(cfg, delay_offsets, num_tasks, k_task, k_delay, event)
     return key, t, nu
@@ -358,8 +323,8 @@ def _sample_activation(cfg: AMTLConfig, delay_offsets: Array, key: Array,
 def _event_draw(cfg: AMTLConfig, delay_offsets: Array, num_tasks: int,
                 k_task: Array, k_delay: Array, event: Array):
     """The per-event draw law: (activated task, staleness nu) from the two
-    keys one chain step splits off.  The one-event engines call it
-    directly and the batch sampler vmaps it, so both draw alike."""
+    keys one chain step splits off.  The dense engine calls it directly
+    and the batch sampler vmaps it, so both draw alike."""
     # Assumption 1: same-rate independent Poisson processes => the next
     # activated node is uniform over tasks.
     t = jax.random.randint(k_task, (), 0, num_tasks)
@@ -390,15 +355,16 @@ def _sample_activation_batch(cfg: AMTLConfig, delay_offsets: Array,
 
     Same splits, same draws, same staleness clamp (`event + i`) as `batch`
     consecutive calls of `_sample_activation` — the event stream is
-    identical to the one-event engines by construction.  Only the chain
-    `key_{i+1} = split(key_i, 3)[0]` is serial, and it is unrolled at
-    trace time (`batch` is static); every draw is a pure function of one
-    chain key, so the draws are vmapped over the batch.  Returns
+    identical to the dense engine's, at any `batch`, by construction.
+    Only the chain `key_{i+1} = split(key_i, 3)[0]` is serial, and it is
+    unrolled at trace time (`batch` is static); every draw is a pure
+    function of one chain key, so the draws are vmapped over the batch.
+    Returns
     (next key, tasks (batch,), stalenesses (batch,), minibatch seeds
     (batch,) uint32).  Each seed is `_minibatch_seed` of the chain key
-    the serial delta engine would hold at that event, so the one-event
-    and batched SGD engines sample identical minibatches; when
-    batch_size is None the seeds are unused (and dead-code-eliminated).
+    held before that event, so every `event_batch` samples identical
+    minibatches; when batch_size is None the seeds are unused (and
+    dead-code-eliminated).
     """
     pre, k_tasks, k_delays = [], [], []
     for _ in range(batch):
@@ -458,75 +424,59 @@ def _one_event_dense(problem: MTLProblem, cfg: AMTLConfig,
     return AMTLState(ring, ptr, state.event + 1, history, key)
 
 
-def _one_event_delta(problem: MTLProblem, cfg: AMTLConfig,
-                     delay_offsets: Array,
-                     state: DeltaAMTLState) -> DeltaAMTLState:
-    """One ARock activation on the delta ring (O(d) state writes/event)."""
-    from repro.kernels.ops import amtl_event
+def _prox_cadence(cfg: AMTLConfig, refresh, state):
+    """(p, p_cache): the server prox a batch reads, and the cache it carries.
 
+    Aligned cadence (prox_every <= event_batch): `refresh` runs every batch
+    and the (0, 0) cache stub rides the carry untouched (no copy).
+    Decoupled cadence (prox_every = k*event_batch): `refresh` runs only at
+    every k-th batch's first event — the events where `event_batch=1` at
+    the same prox_every refreshes — else the carried cache is reused.
+    """
+    if cfg.prox_every <= cfg.event_batch:
+        return refresh(None), state.p_cache
+    do_prox = (state.event % cfg.prox_every) == 0
+    p = jax.lax.cond(do_prox, refresh, lambda _: state.p_cache, None)
+    return p, p
+
+
+def _event_grads(problem: MTLProblem, cfg: AMTLConfig, cols: Array,
+                 p_cols: Array, seeds: Array) -> Array:
+    """(event_batch, d) forward-step gradients of a batch's events.
+
+    Event e's gradient is task cols[e]'s at its prox column p_cols[:, e].
+    It depends only on (task, prox column) — not on v — so duplicate tasks
+    need no serialization here.  Full gradients scan the per-event
+    `task_grad`; with batch_size set each event samples its minibatch from
+    its chain seed `seeds[e]` (`MTLProblem.task_grads_sampled`).
+    """
+    if cfg.batch_size is None:
+        def grad_one(_, inp):
+            t, p_t = inp
+            return None, problem.task_grad(t, p_t)
+
+        _, g_rows = jax.lax.scan(grad_one, None, (cols, p_cols.T))
+        return g_rows
+    return problem.task_grads_sampled(cols, p_cols.T, seeds, cfg.batch_size)
+
+
+def _ring_append(cfg: AMTLConfig, ring: Array, task_ring: Array, ptr: Array,
+                 event: Array, ts: Array, undo_cols: Array):
+    """Append a batch to the undo ring: (ring, task_ring, ptr, event).
+
+    Only the newest `depth` events can ever be rolled back (nu <= tau <
+    depth), so when event_batch > depth the overwritten head of the batch
+    is dropped; the surviving slots are distinct and the scatter is
+    deterministic.
+    """
     depth = cfg.tau + 1
-    use_randomized = cfg.prox_rank is not None and problem.reg_name == "nuclear"
-    with jax.named_scope("amtl.sample"):
-        key, t, nu = _sample_activation(cfg, delay_offsets, state.key,
-                                        problem.num_tasks, state.event)
-        # The minibatch sampling seed is folded off the pre-event key
-        # instead of split from the main chain, so the task/staleness
-        # event stream stays identical to the dense engine.
-        mb_seed = _minibatch_seed(state.key) if cfg.batch_size is not None \
-            else None
-    v = state.v
-
-    def refresh(_):
-        # Lazy stale read: roll back the nu newest undo-log entries, then
-        # patch the node's own (always-current) column.  Only paid when the
-        # server actually recomputes the prox.
-        v_hat = rollback_columns(v, state.delta_ring, state.task_ring,
-                                 state.ptr, nu, cfg.tau)
-        v_hat = v_hat.at[:, t].set(v[:, t])
-        if use_randomized:
-            return svt_randomized(
-                v_hat, jnp.asarray(cfg.eta * problem.lam, v_hat.dtype),
-                rank=cfg.prox_rank, key=k_prox)
-        return backward(problem, v_hat, cfg.eta)
-
-    with jax.named_scope("amtl.prox"):
-        # The sketch key, like the minibatch seed, is folded off the
-        # pre-event key (at a different constant).
-        k_prox = jax.random.fold_in(state.key, 7) if use_randomized else None
-        if cfg.prox_every <= 1:
-            p = refresh(None)
-            p_cache = state.p_cache      # untouched loop carry: no copy
-        else:
-            do_prox = (state.event % cfg.prox_every) == 0
-            p = jax.lax.cond(do_prox, refresh, lambda _: state.p_cache, None)
-            p_cache = p
-
-    with jax.named_scope("amtl.grad"):
-        p_t = p[:, t]
-        if cfg.batch_size is None:
-            g_t = problem.task_grad(t, p_t)
-        else:
-            g_t = problem.task_grad_sampled(t, p_t, mb_seed, cfg.batch_size)
-
-    with jax.named_scope("amtl.update"):
-        history, eta_k = _km_relaxation(cfg, state.history, t, nu)
-
-        # Fused column event: forward step + KM relaxation + undo-log emit.
-        v_t_new, old_col = amtl_event(v[:, t], p_t, g_t,
-                                      jnp.asarray(cfg.eta, p_t.dtype),
-                                      eta_k.astype(p_t.dtype))
-
-        ptr = (state.ptr + 1) % depth
-        return DeltaAMTLState(
-            v=v.at[:, t].set(v_t_new),
-            delta_ring=state.delta_ring.at[ptr].set(old_col),
-            task_ring=state.task_ring.at[ptr].set(t),
-            ptr=ptr,
-            event=state.event + 1,
-            p_cache=p_cache,
-            history=history,
-            key=key,
-        )
+    bsz = cfg.event_batch
+    keep = min(bsz, depth)
+    slots = (ptr + 1 + jnp.arange(bsz - keep, bsz)) % depth
+    return (ring.at[slots].set(undo_cols[bsz - keep:]),
+            task_ring.at[slots].set(ts[bsz - keep:]),
+            (ptr + bsz) % depth,
+            event + bsz)
 
 
 def _one_batch(problem: MTLProblem, cfg: AMTLConfig, delay_offsets: Array,
@@ -536,20 +486,18 @@ def _one_batch(problem: MTLProblem, cfg: AMTLConfig, delay_offsets: Array,
     Serial-replay equivalent: the PRNG chain, the amortized prox schedule
     (refresh at batch-first events that are multiples of prox_every), the
     per-event KM arithmetic, and the undo-log contents all match
-    `event_batch` consecutive `_one_event_delta` steps bitwise on the CPU
-    oracle path — at the aligned cadence (prox_every == event_batch) and
-    the decoupled one (prox_every = k*event_batch, refresh every k-th
-    batch via the carried prox cache).
+    `event_batch` consecutive one-event steps (`event_batch=1`, same
+    prox_every) bitwise on the CPU oracle path — at the aligned cadence
+    (prox_every == event_batch) and the decoupled one (prox_every =
+    k*event_batch, refresh every k-th batch via the carried prox cache).
     """
     from repro.kernels.ops import amtl_event_batch
 
-    depth = cfg.tau + 1
-    bsz = cfg.event_batch
     use_randomized = cfg.prox_rank is not None and problem.reg_name == "nuclear"
     with jax.named_scope("amtl.sample"):
         key, ts, nus, mb_seeds = _sample_activation_batch(
             cfg, delay_offsets, state.key, problem.num_tasks, state.event,
-            bsz)
+            cfg.event_batch)
     v = state.v
 
     # Server prox at the batch's first event: stale read at staleness nu_0
@@ -566,40 +514,14 @@ def _one_batch(problem: MTLProblem, cfg: AMTLConfig, delay_offsets: Array,
         return backward(problem, v_hat, cfg.eta)
 
     with jax.named_scope("amtl.prox"):
-        # Folded off the batch-start key — the key the serial engine would
-        # hold at its refresh event (a refresh batch's first event).
+        # Folded off the batch-start key — the key held at the refresh
+        # event (a refresh batch's first event).
         k_prox = jax.random.fold_in(state.key, 7) if use_randomized else None
-        if cfg.prox_every <= bsz:
-            # Aligned cadence: refresh unconditionally every batch; the
-            # (0, 0) cache stub rides the carry untouched (no copy).
-            p = refresh(None)
-            p_cache = state.p_cache
-        else:
-            # Decoupled cadence: refresh only at every k-th batch's first
-            # event — exactly the events where the serial delta engine at
-            # the same prox_every refreshes — else reuse the carried cache.
-            do_prox = (state.event % cfg.prox_every) == 0
-            p = jax.lax.cond(do_prox, refresh, lambda _: state.p_cache, None)
-            p_cache = p
+        p, p_cache = _prox_cadence(cfg, refresh, state)
 
-    # Per-event forward-step gradients at the batch-constant prox.  g_t
-    # depends only on (t, p[:, t]) — not on v — so duplicates need no
-    # serialization here; the oracle path issues the same per-event ops
-    # as the serial engine, keeping the bits identical.  With batch_size
-    # set each event samples its minibatch from the seed the serial delta
-    # engine would derive at that chain position.
     with jax.named_scope("amtl.grad"):
         p_cols = p[:, ts]                                    # (d, bsz)
-
-        if cfg.batch_size is None:
-            def grad_one(_, inp):
-                t, p_t = inp
-                return None, problem.task_grad(t, p_t)
-
-            _, g_rows = jax.lax.scan(grad_one, None, (ts, p_cols.T))
-        else:
-            g_rows = problem.task_grads_sampled(ts, p_cols.T, mb_seeds,
-                                                cfg.batch_size)  # (bsz, d)
+        g_rows = _event_grads(problem, cfg, ts, p_cols, mb_seeds)
 
     with jax.named_scope("amtl.update"):
         # Delay recording / KM relaxation factors, in event order.
@@ -616,19 +538,15 @@ def _one_batch(problem: MTLProblem, cfg: AMTLConfig, delay_offsets: Array,
             v, p_cols, g_rows.T, ts, jnp.asarray(cfg.eta, v.dtype),
             eta_ks.astype(v.dtype))
 
-        # Ring append, batched.  Only the newest `depth` events can ever be
-        # rolled back (nu <= tau < depth), so when bsz > depth the
-        # overwritten head of the batch is dropped; the surviving slots are
-        # distinct and the scatter is deterministic.
-        keep = min(bsz, depth)
-        slots = (state.ptr + 1 + jnp.arange(bsz - keep, bsz)) % depth
+        delta_ring, task_ring, ptr, event = _ring_append(
+            cfg, state.delta_ring, state.task_ring, state.ptr, state.event,
+            ts, undo_cols)
         return BatchAMTLState(
             v=v_new,
-            delta_ring=state.delta_ring.at[slots].set(
-                undo_cols[bsz - keep:]),
-            task_ring=state.task_ring.at[slots].set(ts[bsz - keep:]),
-            ptr=(state.ptr + bsz) % depth,
-            event=state.event + bsz,
+            delta_ring=delta_ring,
+            task_ring=task_ring,
+            ptr=ptr,
+            event=event,
             p_cache=p_cache,
             history=history,
             key=key,
@@ -687,15 +605,13 @@ def _one_batch_sharded(problem: MTLProblem, cfg: AMTLConfig,
     and the iterates match bitwise on the CPU oracle path; at any shard
     count the event stream and the per-column arithmetic are unchanged.
     """
-    from repro.kernels.ops import amtl_event_batch_sharded
+    from repro.kernels.ops import amtl_event_batch
     from repro.kernels.ref import shard_local_tasks
 
     axis = TASK_AXIS
     n_shards = mesh.shape[axis]
     num_tasks = problem.num_tasks
     n_local = num_tasks // n_shards
-    depth = cfg.tau + 1
-    bsz = cfg.event_batch
     use_randomized = cfg.prox_rank is not None and problem.reg_name == "nuclear"
     distributed = cfg.prox_mode == "distributed"
     plan = ProxPlan(axis=axis, num_tasks=num_tasks, n_local=n_local)
@@ -704,7 +620,7 @@ def _one_batch_sharded(problem: MTLProblem, cfg: AMTLConfig,
         t_off = jax.lax.axis_index(axis) * n_local
         with jax.named_scope("amtl.sample"):
             key, ts, nus, mb_seeds = _sample_activation_batch(
-                cfg, offs, st.key, num_tasks, st.event, bsz)
+                cfg, offs, st.key, num_tasks, st.event, cfg.event_batch)
             lts, owned = shard_local_tasks(ts, t_off, n_local)
             lts_clamped = jnp.where(owned, lts, 0)
         v = st.v                                   # (d, n_local)
@@ -745,17 +661,10 @@ def _one_batch_sharded(problem: MTLProblem, cfg: AMTLConfig,
 
         with jax.named_scope("amtl.prox"):
             # Folded off the batch-start key, replicated — identical to
-            # the serial engines' sketch key.
+            # the batch engine's sketch key.
             k_prox = jax.random.fold_in(st.key, 7) if use_randomized \
                 else None
-            if cfg.prox_every <= bsz:
-                p = refresh(None)
-                p_cache = st.p_cache
-            else:
-                do_prox = (st.event % cfg.prox_every) == 0
-                p = jax.lax.cond(do_prox, refresh, lambda _: st.p_cache,
-                                 None)
-                p_cache = p
+            p, p_cache = _prox_cadence(cfg, refresh, st)
 
         with jax.named_scope("amtl.grad"):
             # Per-event prox columns.  The replicated prox yields the
@@ -769,21 +678,13 @@ def _one_batch_sharded(problem: MTLProblem, cfg: AMTLConfig,
 
             # Forward-step gradients from the shard-local task data.
             # Foreign events run on clamped inputs and are dropped at the
-            # scatter; the owner's expression is the serial engines', on
+            # scatter; the owner's expression is the batch engine's, on
             # the same bits.  Minibatch seeds come from the replicated
             # chain replay, so the owner samples the same rows of its
             # task's (shard-local) data the unsharded engine would at any
             # shard count.
-            if cfg.batch_size is None:
-                def grad_one(_, inp):
-                    t_l, p_t = inp
-                    return None, problem_l.task_grad(t_l, p_t)
-
-                _, g_rows = jax.lax.scan(grad_one, None,
-                                         (lts_clamped, p_cols.T))
-            else:
-                g_rows = problem_l.task_grads_sampled(
-                    lts_clamped, p_cols.T, mb_seeds, cfg.batch_size)
+            g_rows = _event_grads(problem_l, cfg, lts_clamped, p_cols,
+                                  mb_seeds)
 
         with jax.named_scope("amtl.update"):
             # Delay recording / KM relaxation in event order; only the
@@ -801,18 +702,18 @@ def _one_batch_sharded(problem: MTLProblem, cfg: AMTLConfig,
             # sentinel column, dropped inside the op) and private-ring
             # append; the task ring records global ids so later rollbacks
             # can re-mask ownership.
-            v_new, undo_cols = amtl_event_batch_sharded(
+            v_new, undo_cols = amtl_event_batch(
                 v, p_cols, g_rows.T, lts, jnp.asarray(cfg.eta, v.dtype),
                 eta_ks.astype(v.dtype))
 
-            keep = min(bsz, depth)
-            slots = (st.ptr + 1 + jnp.arange(bsz - keep, bsz)) % depth
+            ring, task_ring, ptr, event = _ring_append(
+                cfg, ring, st.task_ring, st.ptr, st.event, ts, undo_cols)
             return ShardedAMTLState(
                 v=v_new,
-                delta_ring=ring.at[slots].set(undo_cols[bsz - keep:])[None],
-                task_ring=st.task_ring.at[slots].set(ts[bsz - keep:]),
-                ptr=(st.ptr + bsz) % depth,
-                event=st.event + bsz,
+                delta_ring=ring[None],
+                task_ring=task_ring,
+                ptr=ptr,
+                event=event,
                 p_cache=p_cache,
                 history=history,
                 key=key,
@@ -859,17 +760,17 @@ def validate_config(cfg: AMTLConfig, reg_name: str | None = None) -> None:
     `reg_name` enables the problem-dependent prox_rank check when the
     caller knows the regularizer.
     """
-    if cfg.engine not in ("delta", "dense", "batch", "sharded"):
+    if cfg.engine not in ("dense", "batch", "sharded"):
         raise ValueError(f"unknown AMTL engine {cfg.engine!r}; "
-                         "expected 'delta', 'dense', 'batch', or 'sharded'")
+                         "expected 'dense', 'batch', or 'sharded'")
     if cfg.prox_every < 1:
         raise ValueError(f"prox_every must be >= 1, got {cfg.prox_every} "
                          "(1 = exact prox every event)")
     if cfg.event_batch < 1:
         raise ValueError(f"event_batch must be >= 1, got {cfg.event_batch}")
-    if cfg.engine in ("dense", "delta") and cfg.event_batch != 1:
+    if cfg.engine == "dense" and cfg.event_batch != 1:
         raise ValueError(
-            f"engine={cfg.engine!r} processes one event per step; "
+            "engine='dense' processes one event per step; "
             f"event_batch={cfg.event_batch} requires engine='batch' or "
             "engine='sharded'")
     if cfg.prox_rank is not None and reg_name is not None \
@@ -881,7 +782,7 @@ def validate_config(cfg: AMTLConfig, reg_name: str | None = None) -> None:
                                   or cfg.prox_rank is not None):
         raise ValueError("engine='dense' is the exact seed baseline; "
                          "prox_every>1 / prox_rank require "
-                         "engine='delta', 'batch', or 'sharded'")
+                         "engine='batch' or 'sharded'")
     if cfg.batch_size is not None:
         if cfg.batch_size < 1:
             raise ValueError(
@@ -890,10 +791,9 @@ def validate_config(cfg: AMTLConfig, reg_name: str | None = None) -> None:
         if cfg.engine == "dense":
             raise ValueError(
                 "engine='dense' is the exact seed baseline and computes "
-                "full gradients only; batch_size requires engine='delta', "
-                "'batch', or 'sharded'")
-    if cfg.engine in ("batch", "sharded") \
-            and cfg.prox_every % cfg.event_batch != 0:
+                "full gradients only; batch_size requires engine='batch' "
+                "or 'sharded'")
+    if cfg.prox_every % cfg.event_batch != 0:
         raise ValueError(
             f"engine={cfg.engine!r} refreshes the server prox only at "
             f"batch boundaries, so prox_every ({cfg.prox_every}) must be a "
@@ -940,8 +840,6 @@ def _resolve_mesh(problem: MTLProblem, cfg: AMTLConfig, mesh):
 def _step_fn(cfg: AMTLConfig, mesh):
     if cfg.engine == "dense":
         return _one_event_dense
-    if cfg.engine == "delta":
-        return _one_event_delta
     if cfg.engine == "batch":
         return _one_batch
     return functools.partial(_one_batch_sharded, mesh=mesh)
@@ -957,10 +855,9 @@ def _run_events(problem: MTLProblem, cfg: AMTLConfig, state,
     call repeatedly.
     """
     step = _step_fn(cfg, mesh)
-    per_step = cfg.event_batch if cfg.engine in ("batch", "sharded") else 1
     with jax.default_matmul_precision(F32_MATMUL):
         return jax.lax.fori_loop(
-            0, num_events // per_step,
+            0, num_events // cfg.event_batch,
             lambda _, s: step(problem, cfg, delay_offsets, s), state)
 
 
@@ -1005,8 +902,7 @@ class AMTLEngine(NamedTuple):
     iterate(state) -> V
         The newest (d, T) iterate held by the state (any engine).
     events_per_step
-        Step granularity: `event_batch` for the batch/sharded engines,
-        1 for dense/delta.
+        Step granularity: `event_batch` (always 1 for dense).
     num_tasks
         T, the problem's task count — so session consumers (the
         learning-while-serving platform in `repro.serve`, examples)
@@ -1035,23 +931,19 @@ def make_engine(problem: MTLProblem, cfg: AMTLConfig,
     if cfg.engine == "dense" and problem.row_counts is not None:
         raise ValueError(
             "engine='dense' is the exact uniform seed baseline; ragged "
-            "problems (row_counts set) require engine='delta', 'batch', "
-            "or 'sharded'")
+            "problems (row_counts set) require engine='batch' or "
+            "'sharded'")
     mesh, n_shards = _resolve_mesh(problem, cfg, mesh)
     num_tasks = problem.num_tasks
-    per_step = cfg.event_batch if cfg.engine in ("batch", "sharded") else 1
     per_refresh = refresh_comm_bytes(cfg, problem.dim, num_tasks, n_shards)
     # Whether a call's sampled gradients take the batched kernel.
-    grads_batched = (cfg.engine in ("batch", "sharded")
-                     and cfg.batch_size is not None
+    grads_batched = (cfg.batch_size is not None
                      and problem.sampled_grads_batched(cfg.batch_size)
                      and ops._on_tpu())
 
     def init(v0: Array, key: Array):
         if cfg.engine == "dense":
             return init_state(cfg, v0, num_tasks, key)
-        if cfg.engine == "delta":
-            return init_delta_state(cfg, v0, num_tasks, key)
         if cfg.engine == "batch":
             return init_batch_state(cfg, v0, num_tasks, key)
         # Placed as `run` returns it, so that the first call compiles the
@@ -1064,10 +956,10 @@ def make_engine(problem: MTLProblem, cfg: AMTLConfig,
                              x, jax.sharding.PartitionSpec)))
 
     def run(state, delay_offsets, num_events: int):
-        if num_events % per_step != 0:
+        if num_events % cfg.event_batch != 0:
             raise ValueError(
                 f"num_events ({num_events}) must be a multiple of "
-                f"event_batch ({per_step}) for engine={cfg.engine!r}")
+                f"event_batch ({cfg.event_batch}) for engine={cfg.engine!r}")
         if delay_offsets is None:
             delay_offsets = jnp.zeros((num_tasks,), jnp.float32)
         # `comm_bytes`: what the call's refreshes move between the shards
@@ -1082,7 +974,7 @@ def make_engine(problem: MTLProblem, cfg: AMTLConfig,
                                int(num_events), mesh)
 
     return AMTLEngine(init=init, run=run, iterate=current_iterate,
-                      events_per_step=per_step, num_tasks=num_tasks)
+                      events_per_step=cfg.event_batch, num_tasks=num_tasks)
 
 
 def amtl_solve(problem: MTLProblem, cfg: AMTLConfig, v0: Array, key: Array,
@@ -1129,8 +1021,8 @@ def amtl_events_only(problem: MTLProblem, cfg: AMTLConfig, v0: Array,
                      delay_offsets: Array | None = None, mesh=None):
     """Run `num_events` activations with NO per-epoch metric tail.
 
-    Returns the final engine state (AMTLState, DeltaAMTLState,
-    BatchAMTLState, or ShardedAMTLState, matching `cfg.engine`).  This is
+    Returns the final engine state (AMTLState, BatchAMTLState, or
+    ShardedAMTLState, matching `cfg.engine`).  This is
     the events/sec benchmark path: it isolates the per-event engine cost
     from the (full-SVD) objective/residual instrumentation of `amtl_solve`.
     Thin wrapper over the session API (init + one `run`).
@@ -1141,14 +1033,14 @@ def amtl_events_only(problem: MTLProblem, cfg: AMTLConfig, v0: Array,
 
 def current_iterate(state) -> Array:
     """The newest iterate V held by any engine's state."""
-    if isinstance(state, (DeltaAMTLState, BatchAMTLState, ShardedAMTLState)):
+    if isinstance(state, (BatchAMTLState, ShardedAMTLState)):
         return state.v
     return state.ring[state.ptr]
 
 
 def default_config(problem: MTLProblem, tau: int = 4, c: float = 0.9,
                    dynamic_step: bool = False, safety: float = 1.0, *,
-                   engine: str = "delta", prox_every: int = 1,
+                   engine: str = "batch", prox_every: int = 1,
                    prox_rank: int | None = None, event_batch: int = 1,
                    prox_mode: str = "replicated",
                    batch_size: int | None = None) -> AMTLConfig:

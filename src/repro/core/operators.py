@@ -112,8 +112,8 @@ def rollback_columns_batch(v: Array, delta_ring: Array, task_ring: Array,
 
     The batch engine uses this at its per-batch prox refresh, where the
     fori_loop's tau sequential (d,)-column writes would serialize for no
-    reason; `rollback_columns` stays as the one-event engines' path and the
-    semantic reference.  The winner selection lives in
+    reason; `rollback_columns` stays as the serial semantic reference the
+    tests check it against.  The winner selection lives in
     `rollback_columns_shard`; this is the t_offset=0 case, where every
     task is owned.
     """

@@ -17,7 +17,6 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels import ref
-from repro.kernels.amtl_event import amtl_event as _amtl_event_pallas
 from repro.kernels.amtl_event_batch import \
     amtl_event_batch as _amtl_event_batch_pallas
 from repro.kernels.gauss_sketch import gauss_sketch as _gauss_sketch_pallas
@@ -39,28 +38,21 @@ def _on_tpu() -> bool:
     return jax.default_backend() == "tpu"
 
 
+def _pallas(use_pallas: bool | None, interpret: bool) -> bool:
+    """Whether a wrapper runs its Pallas kernel: `use_pallas` if given,
+    else on a TPU; `interpret=True` always does (in interpret mode)."""
+    if use_pallas is None:
+        use_pallas = _on_tpu()
+    return use_pallas or interpret
+
+
 @functools.partial(jax.jit, static_argnames=("use_pallas", "interpret"))
 def km_update(v: Array, p: Array, g: Array, eta: Array, eta_k: Array, *,
               use_pallas: bool | None = None,
               interpret: bool = False) -> Array:
-    if use_pallas is None:
-        use_pallas = _on_tpu()
-    if use_pallas or interpret:
+    if _pallas(use_pallas, interpret):
         return _km_pallas(v, p, g, eta, eta_k, interpret=interpret)
     return ref.km_update_ref(v, p, g, eta, eta_k)
-
-
-@functools.partial(jax.jit, static_argnames=("use_pallas", "interpret"))
-def amtl_event(v_t: Array, p_t: Array, g_t: Array, eta: Array, eta_k: Array,
-               *, use_pallas: bool | None = None,
-               interpret: bool = False) -> tuple[Array, Array]:
-    """Fused delta-ring column event: returns (v_new, undo-log entry)."""
-    if use_pallas is None:
-        use_pallas = _on_tpu()
-    if use_pallas or interpret:
-        return _amtl_event_pallas(v_t, p_t, g_t, eta, eta_k,
-                                  interpret=interpret)
-    return ref.amtl_event_ref(v_t, p_t, g_t, eta, eta_k)
 
 
 @functools.partial(jax.jit, static_argnames=("use_pallas", "interpret"))
@@ -72,36 +64,22 @@ def amtl_event_batch(v: Array, p_cols: Array, g_cols: Array, tasks: Array,
 
     Within-batch duplicate tasks serialize in event order (see
     `ref.amtl_event_batch_ref`).  On CPU the oracle path is also the batch
-    engine's hot path — its per-event arithmetic is the serial engines'
+    engine's hot path — its per-event arithmetic is the dense engine's
     expression, which is what the bitwise equivalence tests rely on.
+
+    The sharded engine passes shard-local `tasks` (from
+    `ref.shard_local_tasks`), which carry the sentinel id T_local ==
+    v.shape[1] for events owned by other shards.  Sentinel events are
+    computed on clamped inputs and dropped at the scatter, leaving v's
+    columns untouched; owned events issue bit-for-bit the arithmetic the
+    unsharded batch op would, which is what makes the sharded engine's
+    per-shard execution a masked replay of the global batch rather than a
+    reimplementation.
     """
-    if use_pallas is None:
-        use_pallas = _on_tpu()
-    if use_pallas or interpret:
+    if _pallas(use_pallas, interpret):
         return _amtl_event_batch_pallas(v, p_cols, g_cols, tasks, eta,
                                         eta_ks, interpret=interpret)
     return ref.amtl_event_batch_ref(v, p_cols, g_cols, tasks, eta, eta_ks)
-
-
-@functools.partial(jax.jit, static_argnames=("use_pallas", "interpret"))
-def amtl_event_batch_sharded(v_local: Array, p_cols: Array, g_cols: Array,
-                             local_tasks: Array, eta: Array, eta_ks: Array,
-                             *, use_pallas: bool | None = None,
-                             interpret: bool = False) -> tuple[Array, Array]:
-    """Shard-local batched multi-event update (engine='sharded').
-
-    Same dispatch as `amtl_event_batch`, but `local_tasks` (from
-    `ref.shard_local_tasks`) may carry the sentinel id T_local ==
-    v_local.shape[1] for events owned by other shards.  Sentinel events are
-    computed on clamped inputs and dropped at the scatter, leaving
-    v_local's columns untouched; owned events issue bit-for-bit the
-    arithmetic the unsharded batch op would, which is what makes the
-    sharded engine's per-shard execution a masked replay of the global
-    batch rather than a reimplementation.
-    """
-    return amtl_event_batch(v_local, p_cols, g_cols, local_tasks, eta,
-                            eta_ks, use_pallas=use_pallas,
-                            interpret=interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("use_pallas", "interpret"))
@@ -116,9 +94,7 @@ def svt_reconstruct(qu: Array, s: Array, vt: Array, *,
     arithmetic per backend (the bitwise 1-shard contract on CPU; on TPU
     both take the fused Pallas kernel, so they stay mutually consistent).
     """
-    if use_pallas is None:
-        use_pallas = _on_tpu()
-    if use_pallas or interpret:
+    if _pallas(use_pallas, interpret):
         return _svt_reconstruct_pallas(qu, s, vt, interpret=interpret)
     return ref.svt_reconstruct_ref(qu, s, vt)
 
@@ -126,9 +102,7 @@ def svt_reconstruct(qu: Array, s: Array, vt: Array, *,
 @functools.partial(jax.jit, static_argnames=("use_pallas", "interpret"))
 def l21_prox(w: Array, t: Array, *, use_pallas: bool | None = None,
              interpret: bool = False) -> Array:
-    if use_pallas is None:
-        use_pallas = _on_tpu()
-    if use_pallas or interpret:
+    if _pallas(use_pallas, interpret):
         return _l21_pallas(w, t, interpret=interpret)
     return ref.l21_prox_ref(w, t)
 
@@ -140,9 +114,7 @@ def lstsq_grad(x: Array, w: Array, y: Array, *, n_t: Array | None = None,
     """Fused 2 X^T (X w - y); `n_t` (traced, optional) masks a ragged
     buffer's rows >= n_t out of the residual.  n_t=None is the original
     unmasked expression on both dispatch targets."""
-    if use_pallas is None:
-        use_pallas = _on_tpu()
-    if use_pallas or interpret:
+    if _pallas(use_pallas, interpret):
         return _lstsq_pallas(x, w, y, n_t=n_t, interpret=interpret)
     if n_t is None:
         return ref.lstsq_grad_ref(x, w, y)
@@ -171,9 +143,7 @@ def lstsq_grad_sampled(x: Array, w: Array, y: Array, seed: Array, *,
     bitwise per backend, and n_t == n keeps every bit of the uniform
     path.
     """
-    if use_pallas is None:
-        use_pallas = _on_tpu()
-    if use_pallas or interpret:
+    if _pallas(use_pallas, interpret):
         return _lstsq_sampled_pallas(x, w, y, seed, batch_size=batch_size,
                                      n_t=n_t, interpret=interpret)
     if n_t is None:
@@ -197,9 +167,7 @@ def lstsq_grad_sampled_batch(xs: Array, ys: Array, ts: Array, ws: Array,
     Pallas call; the oracle path scans the events through
     `lstsq_grad_sampled`, bit for bit the per-event engine step.
     """
-    if use_pallas is None:
-        use_pallas = _on_tpu()
-    if use_pallas or interpret:
+    if _pallas(use_pallas, interpret):
         return _lstsq_sampled_batch_pallas(xs, ys, ts, ws, seeds,
                                            batch_size=batch_size,
                                            row_counts=row_counts,
@@ -231,9 +199,7 @@ def sample_mask(n: int, batch_size: int, seed: Array, *,
     n_t) (tests/test_sampling_properties.py pins this).  With ragged
     `n_t`, exactly min(batch_size, n_t) bits are set, all below n_t.
     """
-    if use_pallas is None:
-        use_pallas = _on_tpu()
-    if use_pallas or interpret:
+    if _pallas(use_pallas, interpret):
         return _sample_mask_pallas(n, batch_size, seed, n_t=n_t,
                                    interpret=interpret)
     if n_t is None:
@@ -255,9 +221,7 @@ def gauss_sketch(w: Array, seed: Array, row_offset: Array, *, p: int,
     partitioning rows this way keeps sum_s W_s @ Omega_s = W @ Omega over
     one global Omega, the distributed prox's psum identity.
     """
-    if use_pallas is None:
-        use_pallas = _on_tpu()
-    if use_pallas or interpret:
+    if _pallas(use_pallas, interpret):
         return _gauss_sketch_pallas(w, seed, row_offset, p=p,
                                     interpret=interpret)
     return ref.gauss_sketch_ref(w, seed, row_offset, p)
@@ -273,15 +237,13 @@ def flash_attention(q: Array, k: Array, v: Array, *, causal: bool = True,
     """q: (S, H, hd); k, v: (S, Hkv, hd) — GQA kv heads repeated here.
     Returns (S, H, hd).  Pads S to a 128 multiple and hd to a lane
     multiple before entering the kernel."""
-    if use_pallas is None:
-        use_pallas = _on_tpu()
     s, h, hd = q.shape
     hkv = k.shape[1]
     if hkv != h:
         rep = h // hkv
         k = jnp.repeat(k, rep, axis=1)
         v = jnp.repeat(v, rep, axis=1)
-    if not (use_pallas or interpret):
+    if not _pallas(use_pallas, interpret):
         return ref.sliding_flash_attention_ref(q, k, v, window=window,
                                                causal=causal,
                                                softcap=softcap)
@@ -302,9 +264,7 @@ def rwkv6_scan(r: Array, k: Array, v: Array, w: Array, u: Array, *,
                use_pallas: bool | None = None,
                interpret: bool = False) -> Array:
     """RWKV-6 WKV recurrence.  r,k,v,w: (S, H, D); u: (H, D)."""
-    if use_pallas is None:
-        use_pallas = _on_tpu()
-    if not (use_pallas or interpret):
+    if not _pallas(use_pallas, interpret):
         return ref.rwkv6_scan_ref(r, k, v, w, u)
     from repro.kernels.rwkv6_scan import rwkv6_scan as _wkv
     s = r.shape[0]

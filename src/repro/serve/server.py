@@ -454,8 +454,8 @@ class AMTLServer:
             if self.cfg.engine == "dense":
                 raise ValueError(
                     "engine='dense' is the exact uniform baseline and "
-                    "cannot grow ragged cohorts; use engine='delta', "
-                    "'batch', or 'sharded' for label-carrying feedback")
+                    "cannot grow ragged cohorts; use engine='batch' or "
+                    "'sharded' for label-carrying feedback")
             x = np.asarray(features, np.float32)
             if x.ndim == 1:
                 x = x[None, :]

@@ -1,6 +1,7 @@
-"""Batched multi-event AMTL engine: bitwise serial-replay equivalence for
-aligned configs, within-batch conflict semantics, the amtl_event_batch
-kernel/oracle, and the AMTLConfig validation surface for engine='batch'."""
+"""Batched multi-event AMTL engine: `event_batch` leaves the result
+unchanged (bitwise serial replay of event_batch=1) for aligned configs,
+within-batch conflict semantics, the amtl_event_batch kernel/oracle, and
+the AMTLConfig validation surface for engine='batch'."""
 import numpy as np
 import pytest
 import jax
@@ -21,32 +22,38 @@ def _base_cfg(problem, tau=3, **kw):
 
 
 def _batch_pair(problem, tau, bsz, **kw):
-    """(delta cfg, batch cfg) aligned: prox_every == event_batch."""
-    delta = _base_cfg(problem, tau=tau, engine="delta", prox_every=bsz, **kw)
-    batch = delta._replace(engine="batch", event_batch=bsz)
-    return delta, batch
+    """(one-event cfg, batch cfg) aligned: prox_every == event_batch.
+
+    The one-event reference is the batch engine at event_batch=1 and the
+    same prox_every; at bsz=1 (prox_every=1, where the two would be one
+    config) it is the seed dense engine."""
+    batch = _base_cfg(problem, tau=tau, engine="batch", prox_every=bsz,
+                      event_batch=bsz, **kw)
+    one = batch._replace(engine="dense" if bsz == 1 else "batch",
+                         event_batch=1)
+    return one, batch
 
 
 # ----------------------------------------------------------- equivalence
 @pytest.mark.parametrize("tau,bsz", [(0, 5), (3, 5), (8, 5), (3, 1), (4, 10)])
 def test_batch_engine_bitwise_matches_delta(small_problem, tau, bsz):
     """Aligned configs (prox_every == event_batch, same key): the batch
-    engine replays the serial delta engine's iterates bitwise on the CPU
-    oracle path.  tau=3/bsz=5 exercises event_batch > ring depth (only the
-    newest tau+1 undo entries survive a batch)."""
-    delta_cfg, batch_cfg = _batch_pair(small_problem, tau, bsz)
+    engine replays the one-event iterates bitwise on the CPU oracle path.
+    tau=3/bsz=5 exercises event_batch > ring depth (only the newest tau+1
+    undo entries survive a batch)."""
+    one_cfg, batch_cfg = _batch_pair(small_problem, tau, bsz)
     w0 = jnp.zeros((small_problem.dim, small_problem.num_tasks), jnp.float32)
     key = jax.random.PRNGKey(3)
     epe = 10 if bsz != 5 else 5
-    delta = amtl_solve(small_problem, delta_cfg, w0, key, num_epochs=8,
-                       events_per_epoch=epe)
+    one = amtl_solve(small_problem, one_cfg, w0, key, num_epochs=8,
+                     events_per_epoch=epe)
     batch = amtl_solve(small_problem, batch_cfg, w0, key, num_epochs=8,
                        events_per_epoch=epe)
-    np.testing.assert_array_equal(np.asarray(delta.v), np.asarray(batch.v))
-    np.testing.assert_array_equal(np.asarray(delta.w), np.asarray(batch.w))
-    np.testing.assert_array_equal(np.asarray(delta.objectives),
+    np.testing.assert_array_equal(np.asarray(one.v), np.asarray(batch.v))
+    np.testing.assert_array_equal(np.asarray(one.w), np.asarray(batch.w))
+    np.testing.assert_array_equal(np.asarray(one.objectives),
                                   np.asarray(batch.objectives))
-    np.testing.assert_array_equal(np.asarray(delta.residuals),
+    np.testing.assert_array_equal(np.asarray(one.residuals),
                                   np.asarray(batch.residuals))
 
 
@@ -54,26 +61,26 @@ def test_batch_engine_bitwise_under_delays_dynamic_step_and_sketch(
         small_problem):
     """The batch engine must replay the delay history (per-event recording
     order), the delay-adaptive KM step, and the folded sketch key exactly."""
-    delta_cfg, batch_cfg = _batch_pair(small_problem, tau=4, bsz=5,
-                                       dynamic_step=True, prox_rank=5)
+    one_cfg, batch_cfg = _batch_pair(small_problem, tau=4, bsz=5,
+                                     dynamic_step=True, prox_rank=5)
     offsets = jnp.asarray([3.0, 1.0, 0.0, 2.0, 4.0])
     w0 = jnp.zeros((small_problem.dim, small_problem.num_tasks), jnp.float32)
     key = jax.random.PRNGKey(11)
-    delta = amtl_solve(small_problem, delta_cfg, w0, key, num_epochs=6,
-                       delay_offsets=offsets)
+    one = amtl_solve(small_problem, one_cfg, w0, key, num_epochs=6,
+                     delay_offsets=offsets)
     batch = amtl_solve(small_problem, batch_cfg, w0, key, num_epochs=6,
                        delay_offsets=offsets)
-    np.testing.assert_array_equal(np.asarray(delta.v), np.asarray(batch.v))
+    np.testing.assert_array_equal(np.asarray(one.v), np.asarray(batch.v))
 
 
 def test_batch_engine_state_stream_matches_delta(small_problem):
     """Beyond the iterate: the undo ring, ring pointer, event counter, PRNG
     key, and delay history of the batch engine must equal serial replay —
     they are what the next batch's stale read is reconstructed from."""
-    delta_cfg, batch_cfg = _batch_pair(small_problem, tau=3, bsz=5)
+    one_cfg, batch_cfg = _batch_pair(small_problem, tau=3, bsz=5)
     w0 = jnp.zeros((small_problem.dim, small_problem.num_tasks), jnp.float32)
     key = jax.random.PRNGKey(5)
-    d = amtl_events_only(small_problem, delta_cfg, w0, key, 25)
+    d = amtl_events_only(small_problem, one_cfg, w0, key, 25)
     b = amtl_events_only(small_problem, batch_cfg, w0, key, 25)
     np.testing.assert_array_equal(np.asarray(d.v), np.asarray(b.v))
     np.testing.assert_array_equal(np.asarray(d.delta_ring),
@@ -110,7 +117,7 @@ def test_event_batch_must_be_positive(small_problem):
                        w0, key, num_epochs=1)
 
 
-@pytest.mark.parametrize("engine", ["dense", "delta"])
+@pytest.mark.parametrize("engine", ["dense"])
 def test_one_event_engines_reject_event_batch(small_problem, engine):
     """The error must name event_batch (the offending parameter), not the
     prox knobs."""
@@ -223,10 +230,14 @@ def test_batch_ref_matches_numpy_serial_replay():
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 @pytest.mark.parametrize("d,T,b", [(20, 5, 8), (128, 128, 64), (1000, 7, 3),
-                                   (260, 130, 5)])
+                                   (260, 130, 5),
+                                   # one event a step (event_batch=1)
+                                   (7, 3, 1), (128, 128, 1), (1000, 7, 1),
+                                   (1024, 130, 1), (5000, 5, 1)])
 def test_amtl_event_batch_kernel_matches_ref(d, T, b, dtype):
     """Interpret-mode Pallas kernel vs the jnp oracle, duplicate-free and
-    duplicate-heavy shapes, padded and exact lane counts."""
+    duplicate-heavy shapes, padded and exact lane counts.  A single event's
+    undo-log entry must be its column's exact pre-write bits."""
     v, p, g, tasks, eta, eta_ks = _random_batch(d, T, b, d + b, dtype)
     got_v, got_u = amtl_event_batch_pallas(v, p, g, tasks, eta, eta_ks,
                                            interpret=True)
@@ -238,6 +249,9 @@ def test_amtl_event_batch_kernel_matches_ref(d, T, b, dtype):
                                np.asarray(want_v), rtol=tol, atol=tol)
     np.testing.assert_allclose(np.asarray(got_u, np.float32),
                                np.asarray(want_u), rtol=tol, atol=tol)
+    if b == 1:
+        np.testing.assert_array_equal(np.asarray(got_u[0]),
+                                      np.asarray(v[:, tasks[0]]))
 
 
 def test_amtl_event_batch_kernel_duplicates_serialize():
@@ -253,9 +267,10 @@ def test_amtl_event_batch_kernel_duplicates_serialize():
                                atol=1e-5)
 
 
-def test_amtl_event_batch_ops_dispatch_cpu_is_oracle():
+@pytest.mark.parametrize("b", [7, 1])
+def test_amtl_event_batch_ops_dispatch_cpu_is_oracle(b):
     """On CPU the ops wrapper must hit the jnp oracle path bitwise."""
-    v, p, g, tasks, eta, eta_ks = _random_batch(129, 6, 7, 2)
+    v, p, g, tasks, eta, eta_ks = _random_batch(129, 6, b, 2)
     got_v, got_u = amtl_event_batch(v, p, g, tasks, eta, eta_ks)
     want_v, want_u = jax.jit(ref.amtl_event_batch_ref)(v, p, g, tasks, eta,
                                                        eta_ks)

@@ -1,5 +1,6 @@
-"""Delta-ring AMTL engine: event-for-event equivalence with the seed dense
-ring, prox amortization (paper §III-C), and the amtl_event kernel oracle."""
+"""The delta ring one event a step (engine='batch', event_batch=1):
+event-for-event equivalence with the seed dense ring, prox amortization
+(paper §III-C), and the undo-log rollback."""
 import numpy as np
 import pytest
 import jax
@@ -8,9 +9,6 @@ import jax.numpy as jnp
 from repro.core import AMTLConfig, amtl_solve
 from repro.core.amtl import amtl_events_only, current_iterate
 from repro.core.operators import rollback_columns
-from repro.kernels import ref
-from repro.kernels.amtl_event import amtl_event as amtl_event_pallas
-from repro.kernels.ops import amtl_event
 
 
 def _base_cfg(problem, tau=3, **kw):
@@ -21,21 +19,22 @@ def _base_cfg(problem, tau=3, **kw):
 # ----------------------------------------------------------- equivalence
 @pytest.mark.parametrize("tau", [0, 1, 3, 8])
 def test_delta_engine_bitwise_matches_dense(small_problem, tau):
-    """Same PRNG key, prox_every=1: the delta ring reconstructs exactly the
-    stale reads of the seed (tau+1, d, T) ring, event for event."""
+    """Same PRNG key, prox_every=1: the delta ring, one event a step,
+    reconstructs exactly the stale reads of the seed (tau+1, d, T) ring,
+    event for event."""
     cfg = _base_cfg(small_problem, tau=tau)
     w0 = jnp.zeros((small_problem.dim, small_problem.num_tasks), jnp.float32)
     key = jax.random.PRNGKey(3)
     dense = amtl_solve(small_problem, cfg._replace(engine="dense"), w0, key,
                        num_epochs=8)
-    delta = amtl_solve(small_problem, cfg._replace(engine="delta"), w0, key,
-                       num_epochs=8)
-    np.testing.assert_array_equal(np.asarray(dense.v), np.asarray(delta.v))
-    np.testing.assert_array_equal(np.asarray(dense.w), np.asarray(delta.w))
+    one_event = amtl_solve(small_problem, cfg._replace(engine="batch"), w0,
+                           key, num_epochs=8)
+    np.testing.assert_array_equal(np.asarray(dense.v), np.asarray(one_event.v))
+    np.testing.assert_array_equal(np.asarray(dense.w), np.asarray(one_event.w))
     np.testing.assert_array_equal(np.asarray(dense.objectives),
-                                  np.asarray(delta.objectives))
+                                  np.asarray(one_event.objectives))
     np.testing.assert_array_equal(np.asarray(dense.residuals),
-                                  np.asarray(delta.residuals))
+                                  np.asarray(one_event.residuals))
 
 
 def test_delta_engine_bitwise_under_delays_and_dynamic_step(small_problem):
@@ -47,9 +46,9 @@ def test_delta_engine_bitwise_under_delays_and_dynamic_step(small_problem):
     key = jax.random.PRNGKey(11)
     dense = amtl_solve(small_problem, cfg._replace(engine="dense"), w0, key,
                        num_epochs=6, delay_offsets=offsets)
-    delta = amtl_solve(small_problem, cfg._replace(engine="delta"), w0, key,
-                       num_epochs=6, delay_offsets=offsets)
-    np.testing.assert_array_equal(np.asarray(dense.v), np.asarray(delta.v))
+    one_event = amtl_solve(small_problem, cfg._replace(engine="batch"), w0,
+                           key, num_epochs=6, delay_offsets=offsets)
+    np.testing.assert_array_equal(np.asarray(dense.v), np.asarray(one_event.v))
 
 
 def test_events_only_matches_solve(small_problem):
@@ -72,6 +71,11 @@ def test_engine_config_validation(small_problem):
                    w0, key, num_epochs=1)
     with pytest.raises(ValueError, match="unknown AMTL engine"):
         amtl_solve(small_problem, _base_cfg(small_problem, engine="sparse"),
+                   w0, key, num_epochs=1)
+    # the one-event delta engine is the batch engine at event_batch=1 now
+    with pytest.raises(ValueError, match=r"unknown AMTL engine 'delta'; "
+                       r"expected 'dense', 'batch', or 'sharded'"):
+        amtl_solve(small_problem, _base_cfg(small_problem, engine="delta"),
                    w0, key, num_epochs=1)
 
 
@@ -193,38 +197,3 @@ def test_rollback_columns_replays_undo_log():
                                jnp.asarray(ptr, jnp.int32),
                                jnp.asarray(nu, jnp.int32), tau)
         np.testing.assert_array_equal(np.asarray(got), history[len(history) - 1 - nu])
-
-
-# ------------------------------------------------------- kernel validation
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-@pytest.mark.parametrize("d", [7, 128, 1000, 1024, 5000])
-def test_amtl_event_kernel_matches_ref(d, dtype):
-    """Interpret-mode Pallas kernel vs the jnp oracle; the undo-log output
-    must be the exact pre-write bits."""
-    kv, kp, kg = jax.random.split(jax.random.PRNGKey(0), 3)
-    v = jax.random.normal(kv, (d,), dtype)
-    p = jax.random.normal(kp, (d,), dtype)
-    g = jax.random.normal(kg, (d,), dtype)
-    eta, eta_k = jnp.asarray(0.05), jnp.asarray(0.8)
-    got_v, got_old = amtl_event_pallas(v, p, g, eta, eta_k, interpret=True)
-    want_v, _ = ref.amtl_event_ref(v.astype(jnp.float32),
-                                   p.astype(jnp.float32),
-                                   g.astype(jnp.float32), eta, eta_k)
-    tol = 2e-2 if dtype == jnp.bfloat16 else 1e-5
-    np.testing.assert_allclose(np.asarray(got_v, np.float32),
-                               np.asarray(want_v), rtol=tol, atol=tol)
-    np.testing.assert_array_equal(np.asarray(got_old), np.asarray(v))
-
-
-def test_amtl_event_ops_dispatch_cpu_is_oracle():
-    """On CPU the ops wrapper must hit the jnp oracle path bitwise."""
-    kv, kp, kg = jax.random.split(jax.random.PRNGKey(2), 3)
-    v, p, g = (jax.random.normal(kk, (513,)) for kk in (kv, kp, kg))
-    eta, eta_k = jnp.asarray(0.1), jnp.asarray(0.6)
-    got_v, got_old = amtl_event(v, p, g, eta, eta_k)
-    want_v, want_old = ref.amtl_event_ref(v, p, g, eta, eta_k)
-    # jit may contract the mul-adds into FMAs, so the update matches to ulp
-    # tolerance; the undo-log output is a verbatim copy and must be exact.
-    np.testing.assert_allclose(np.asarray(got_v), np.asarray(want_v),
-                               rtol=1e-6, atol=1e-7)
-    np.testing.assert_array_equal(np.asarray(got_old), np.asarray(want_old))

@@ -18,7 +18,7 @@ from repro.core.operators import (rollback_columns_batch,
 from repro.core.prox import (ProxPlan, sketch_width, svt_randomized,
                              svt_randomized_dist)
 from repro.distributed.sharding import TASK_AXIS
-from repro.kernels.ops import amtl_event_batch, amtl_event_batch_sharded
+from repro.kernels.ops import amtl_event_batch
 from repro.kernels.ref import shard_local_tasks
 from repro.launch.mesh import make_task_mesh
 
@@ -226,8 +226,8 @@ def test_sharded_batch_dispatch_drops_sentinel_events():
     want_v, want_u = amtl_event_batch(v, p, g, tasks, eta, eta_ks)
     local, owned = shard_local_tasks(tasks, jnp.asarray(t_off, jnp.int32),
                                      n_local)
-    got_v, got_u = amtl_event_batch_sharded(v[:, t_off:t_off + n_local], p,
-                                            g, local, eta, eta_ks)
+    got_v, got_u = amtl_event_batch(v[:, t_off:t_off + n_local], p, g,
+                                    local, eta, eta_ks)
     np.testing.assert_array_equal(np.asarray(got_v),
                                   np.asarray(want_v[:, t_off:t_off + n_local]))
     np.testing.assert_array_equal(
@@ -288,7 +288,7 @@ def test_sharded_requires_tasks_axis(small_problem):
 def test_mesh_rejected_for_unsharded_engines(small_problem, mesh1):
     w0 = jnp.zeros((small_problem.dim, small_problem.num_tasks), jnp.float32)
     eta = 1.0 / small_problem.lipschitz()
-    cfg = AMTLConfig(eta=eta, eta_k=0.7, tau=3, engine="delta")
+    cfg = AMTLConfig(eta=eta, eta_k=0.7, tau=3, engine="batch")
     with pytest.raises(ValueError, match=r"mesh is only meaningful.*sharded"):
         amtl_solve(small_problem, cfg, w0, jax.random.PRNGKey(0),
                    num_epochs=1, mesh=mesh1)

@@ -7,8 +7,8 @@ Covers the session redesign's three contracts:
   * every engine state round-trips through `repro.checkpoint.save/restore`
     and resumes bitwise, including the sharded state under a mesh;
   * the decoupled prox cadence (`prox_every = k * event_batch`) reproduces
-    the serial delta engine bitwise at matched cadence on the CPU oracle
-    path, for the batch and sharded engines.
+    the one-event run (event_batch=1) bitwise at the same prox_every on
+    the CPU oracle path, for the batch and sharded engines.
 
 Plus the `default_config` engine-kwarg validation surface and the
 backward-compat contract of the `amtl_solve`/`amtl_events_only` wrappers.
@@ -27,11 +27,15 @@ from repro.core.amtl import (BatchAMTLState, ShardedAMTLState,
                              amtl_events_only, current_iterate)
 from repro.launch.mesh import make_task_mesh
 
-ENGINES = ("dense", "delta", "batch", "sharded")
+# "batch1": the batch engine one event a step (event_batch=1).
+ENGINES = ("dense", "batch1", "batch", "sharded")
 
 
 def _cfg(problem, engine, tau=3, **kw):
     eta = 1.0 / problem.lipschitz()
+    if engine == "batch1":
+        engine = "batch"
+        kw.setdefault("event_batch", 1)
     if engine in ("batch", "sharded"):
         kw.setdefault("event_batch", 4)
         kw.setdefault("prox_every", kw["event_batch"])
@@ -106,7 +110,7 @@ def test_make_engine_validates_eagerly(small_problem, mesh1):
     with pytest.raises(ValueError, match="unknown AMTL engine"):
         make_engine(small_problem, _cfg(small_problem, "sparse"))
     with pytest.raises(ValueError, match=r"mesh is only meaningful"):
-        make_engine(small_problem, _cfg(small_problem, "delta"), mesh1)
+        make_engine(small_problem, _cfg(small_problem, "batch1"), mesh1)
 
 
 # -------------------------------------------------------- split / resume
@@ -194,16 +198,16 @@ def test_checkpoint_restore_rejects_layout_drift(small_problem, tmp_path):
 @pytest.mark.parametrize("tau,bsz,k", [(3, 4, 2), (3, 4, 3), (0, 2, 4),
                                        (3, 5, 2), (8, 5, 3)])
 def test_batch_decoupled_cadence_matches_delta(small_problem, tau, bsz, k):
-    """prox_every = k*event_batch reproduces the serial delta engine at the
-    same prox cadence bitwise on the CPU oracle path — full state including
-    the carried prox cache.  (3,5,2)/(8,5,3) cover event_batch > ring
-    depth and deep rings."""
-    delta_cfg = _cfg(small_problem, "delta", tau=tau, prox_every=k * bsz)
-    batch_cfg = delta_cfg._replace(engine="batch", event_batch=bsz)
+    """prox_every = k*event_batch reproduces the one-event run at the same
+    prox cadence bitwise on the CPU oracle path — full state including the
+    carried prox cache.  (3,5,2)/(8,5,3) cover event_batch > ring depth
+    and deep rings."""
+    one_cfg = _cfg(small_problem, "batch1", tau=tau, prox_every=k * bsz)
+    batch_cfg = one_cfg._replace(event_batch=bsz)
     w0 = jnp.zeros((small_problem.dim, small_problem.num_tasks), jnp.float32)
     key = jax.random.PRNGKey(3)
     n = 6 * k * bsz
-    d = amtl_events_only(small_problem, delta_cfg, w0, key, n)
+    d = amtl_events_only(small_problem, one_cfg, w0, key, n)
     b = amtl_events_only(small_problem, batch_cfg, w0, key, n)
     np.testing.assert_array_equal(np.asarray(d.v), np.asarray(b.v))
     np.testing.assert_array_equal(np.asarray(d.p_cache),
@@ -219,14 +223,15 @@ def test_batch_decoupled_cadence_matches_delta(small_problem, tau, bsz, k):
 
 def test_batch_decoupled_cadence_dynamic_step_and_sketch(small_problem):
     """Cadence decoupling must also replay the delay-adaptive KM step and
-    fold the sketch key at refresh events only, exactly like delta."""
-    delta_cfg = _cfg(small_problem, "delta", tau=4, prox_every=10,
-                     dynamic_step=True, prox_rank=5)
-    batch_cfg = delta_cfg._replace(engine="batch", event_batch=5)
+    fold the sketch key at refresh events only, exactly like one event a
+    step."""
+    one_cfg = _cfg(small_problem, "batch1", tau=4, prox_every=10,
+                   dynamic_step=True, prox_rank=5)
+    batch_cfg = one_cfg._replace(event_batch=5)
     offs = jnp.asarray([3.0, 1.0, 0.0, 2.0, 4.0])
     w0 = jnp.zeros((small_problem.dim, small_problem.num_tasks), jnp.float32)
     key = jax.random.PRNGKey(11)
-    d = amtl_events_only(small_problem, delta_cfg, w0, key, 40,
+    d = amtl_events_only(small_problem, one_cfg, w0, key, 40,
                          delay_offsets=offs)
     b = amtl_events_only(small_problem, batch_cfg, w0, key, 40,
                          delay_offsets=offs)
@@ -283,13 +288,19 @@ def test_default_config_accepts_engine_kwargs(small_problem):
     # the returned config must be directly usable
     eng = make_engine(small_problem, cfg)
     assert eng.events_per_step == 8
+    # the default is the batch engine, one event a step
+    assert default_config(small_problem).engine == "batch"
+    eng = make_engine(small_problem, AMTLConfig(cfg.eta, cfg.eta_k, cfg.tau))
+    assert eng.events_per_step == 1
+    w0 = jnp.zeros((small_problem.dim, small_problem.num_tasks), jnp.float32)
+    assert isinstance(eng.init(w0, jax.random.PRNGKey(0)), BatchAMTLState)
 
 
 def test_default_config_validates_like_make_engine(small_problem):
     """Invalid engine combinations fail at config construction, through
     the same validate_config path make_engine runs."""
     with pytest.raises(ValueError, match=r"event_batch=4.*engine='batch'"):
-        default_config(small_problem, engine="delta", event_batch=4)
+        default_config(small_problem, engine="dense", event_batch=4)
     with pytest.raises(ValueError, match="unknown AMTL engine"):
         default_config(small_problem, engine="sparse")
     with pytest.raises(ValueError, match=r"must be a multiple of"):
@@ -299,18 +310,18 @@ def test_default_config_validates_like_make_engine(small_problem):
         default_config(small_problem, engine="dense", prox_every=2)
     l21 = small_problem._replace(reg_name="l21")
     with pytest.raises(ValueError, match=r"prox_rank.*nuclear.*'l21'"):
-        default_config(l21, engine="delta", prox_rank=3)
+        default_config(l21, engine="batch", prox_rank=3)
 
 
 def test_validate_config_standalone(small_problem):
     validate_config(_cfg(small_problem, "batch", event_batch=4,
                          prox_every=12))
     with pytest.raises(ValueError, match="prox_every must be >= 1"):
-        validate_config(_cfg(small_problem, "delta", prox_every=0))
+        validate_config(_cfg(small_problem, "batch1", prox_every=0))
 
 
 # ------------------------------------------------------------ phase names
-@pytest.mark.parametrize("engine", ("delta", "batch", "sharded"))
+@pytest.mark.parametrize("engine", ("batch1", "batch", "sharded"))
 def test_engine_step_names_its_phases(small_problem, mesh1, engine):
     """The sampling, prox, gradient and update phases are named scopes of
     the lowered program (what a device trace attributes time to)."""

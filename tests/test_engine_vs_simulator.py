@@ -4,11 +4,12 @@ faithful float64 discrete-event reference dynamics.
 `core/simulator.py::simulate_amtl` executes the exact §III.4 mathematics in
 float64 numpy with explicit node clocks and stale snapshot reads — it is
 the repo's ground-truth AMTL dynamics, previously only compared to the
-jitted engines indirectly.  This suite runs all four engines
-(dense/delta/batch/sharded) on the same `make_synthetic` problem with the
-same (eta, eta_k, tau) and asserts:
+jitted engines indirectly.  This suite runs the three engines — dense,
+batch (as the default config, `batch1`, and named explicitly) and sharded
+— on the same `make_synthetic` problem with the same (eta, eta_k, tau)
+and asserts:
 
-  * the four engines produce the SAME iterates bitwise (at prox_every=1 /
+  * the engines produce the SAME iterates bitwise (at prox_every=1 /
     event_batch=1 their event streams coincide by construction);
   * every engine's objective trajectory tracks the simulator's at equal
     event counts — loosely early (the two executions activate tasks in
@@ -28,7 +29,12 @@ from repro.core.operators import amtl_max_step
 from repro.launch.mesh import make_task_mesh
 
 T, D, N, TAU, EPOCHS = 4, 12, 30, 4, 400
-ENGINES = ("dense", "delta", "batch", "sharded")
+# "batch1": the default config, the batch engine one event a step.
+ENGINES = ("dense", "batch1", "batch", "sharded")
+
+
+def _engine_kw(engine):
+    return {} if engine == "batch1" else {"engine": engine}
 
 
 @pytest.fixture(scope="module")
@@ -65,11 +71,11 @@ def engine_runs(stacked_problem):
     key = jax.random.PRNGKey(0)
     out = {}
     for engine in ENGINES:
-        cfg = AMTLConfig(eta=eta, eta_k=eta_k, tau=TAU, engine=engine)
+        cfg = AMTLConfig(eta=eta, eta_k=eta_k, tau=TAU, **_engine_kw(engine))
         mesh = None
         if engine in ("batch", "sharded"):
-            # event_batch=1 keeps the amortized-prox schedule identical to
-            # the one-event engines, so all four event streams coincide.
+            # event_batch=1 keeps the prox schedule identical to the dense
+            # engine's, so all the event streams coincide.
             cfg = cfg._replace(event_batch=1, prox_every=1)
         if engine == "sharded":
             mesh = make_task_mesh(1)
@@ -79,7 +85,7 @@ def engine_runs(stacked_problem):
 
 
 def test_engines_agree_bitwise_with_each_other(engine_runs):
-    """At prox_every=1/event_batch=1 all four engines replay the same event
+    """At prox_every=1/event_batch=1 all the engines replay the same event
     stream and arithmetic — iterates and trajectories must be identical."""
     ref = engine_runs["dense"]
     for engine in ENGINES[1:]:
@@ -126,7 +132,7 @@ def test_final_iterate_matches_float64_reference(engine, engine_runs,
 # not bitwise.
 
 BSZ = 10  # of N=30 samples: a genuine 3x-variance minibatch
-SGD_ENGINES = ("delta", "batch", "sharded")  # dense rejects batch_size
+SGD_ENGINES = ("batch1", "batch", "sharded")  # dense rejects batch_size
 
 
 @pytest.fixture(scope="module")
@@ -148,8 +154,8 @@ def sgd_engine_runs(stacked_problem):
     key = jax.random.PRNGKey(0)
     out = {}
     for engine in SGD_ENGINES:
-        cfg = AMTLConfig(eta=eta, eta_k=eta_k, tau=TAU, engine=engine,
-                         batch_size=BSZ)
+        cfg = AMTLConfig(eta=eta, eta_k=eta_k, tau=TAU, batch_size=BSZ,
+                         **_engine_kw(engine))
         mesh = None
         if engine in ("batch", "sharded"):
             cfg = cfg._replace(event_batch=1, prox_every=1)
@@ -161,10 +167,10 @@ def sgd_engine_runs(stacked_problem):
 
 
 def test_sgd_engines_agree_bitwise_with_each_other(sgd_engine_runs):
-    """All three minibatch engines fold the same per-event sampling seed
+    """All the minibatch runs fold the same per-event sampling seed
     off the same chain position — with coincident event streams their
     iterates must stay bitwise identical."""
-    ref = sgd_engine_runs["delta"]
+    ref = sgd_engine_runs["batch1"]
     for engine in SGD_ENGINES[1:]:
         res = sgd_engine_runs[engine]
         np.testing.assert_array_equal(np.asarray(ref.v), np.asarray(res.v),
@@ -210,7 +216,7 @@ def test_sgd_clamp_batch_size_above_n_is_bitwise_full(stacked_problem):
     w0 = jnp.zeros((D, T), jnp.float32)
     key = jax.random.PRNGKey(0)
     full_cfg = AMTLConfig(eta=eta, eta_k=amtl_max_step(TAU, T), tau=TAU,
-                          engine="delta")
+                          engine="batch")
     sgd_cfg = full_cfg._replace(batch_size=N + 69)
     full = amtl_solve(stacked_problem, full_cfg, w0, key, num_epochs=50)
     sgd = amtl_solve(stacked_problem, sgd_cfg, w0, key, num_epochs=50)

@@ -1,7 +1,7 @@
 """Property-based tests of the serial-replay contracts.
 
 `_sample_activation_batch` is what lets the batch and sharded engines claim
-an event stream identical to the one-event engines BY CONSTRUCTION: it must
+an event stream identical to the dense engine's BY CONSTRUCTION: it must
 consume the same PRNG splits and produce the same (task, staleness) draws
 as `event_batch` consecutive `_sample_activation` calls — including the
 per-position staleness clamp `nu <= min(tau, event + i)` — for every
@@ -61,7 +61,7 @@ def test_batch_sampler_replays_serial_chain_exactly(setup):
     want_ts, want_nus, want_seeds = [], [], []
     for i in range(batch):
         # the minibatch seed is folded off the PRE-event chain key — the
-        # exact key the serial delta engine holds when it derives its seed
+        # exact key a one-event step holds when it derives its seed
         want_seeds.append(int(_minibatch_seed(key)))
         key, t, nu = _sample_activation(cfg, offs, key, num_tasks,
                                         event0_j + i)
@@ -147,7 +147,8 @@ def _tiny_problem():
 
 @st.composite
 def _session_setups(draw):
-    engine = draw(st.sampled_from(["dense", "delta", "batch", "sharded"]))
+    # "batch1": the batch engine one event a step, any prox cadence
+    engine = draw(st.sampled_from(["dense", "batch1", "batch", "sharded"]))
     tau = draw(st.integers(0, 4))
     if engine in ("batch", "sharded"):
         bsz = draw(st.integers(1, 4))
@@ -155,6 +156,7 @@ def _session_setups(draw):
     else:
         bsz = 1
         prox_every = 1 if engine == "dense" else draw(st.integers(1, 4))
+        engine = "batch" if engine == "batch1" else engine
     total_steps = draw(st.integers(1, 5))
     split = draw(st.integers(0, total_steps))
     dynamic = draw(st.booleans())
@@ -278,17 +280,17 @@ def test_minibatch_gradient_is_unbiased_over_seeds():
 @given(st.integers(0, 2**31 - 1), st.integers(1, _N), st.integers(0, 4))
 def test_delta_and_batch_engines_agree_bitwise_with_minibatching(
         seed, batch_size, tau):
-    """Aligned delta/batch configs with batch_size set: both engines must
-    fold the SAME per-event sampling seed off the same chain position, so
-    their full states stay bitwise equal on the CPU oracle path."""
+    """Aligned configs with batch_size set, one event a step and three: both
+    must fold the SAME per-event sampling seed off the same chain position,
+    so their full states stay bitwise equal on the CPU oracle path."""
     problem = _tiny_problem()
     eta = 1.0 / problem.lipschitz()
-    delta_cfg = AMTLConfig(eta=eta, eta_k=0.6, tau=tau, engine="delta",
-                           prox_every=3, batch_size=batch_size)
-    batch_cfg = delta_cfg._replace(engine="batch", event_batch=3)
+    one_cfg = AMTLConfig(eta=eta, eta_k=0.6, tau=tau, engine="batch",
+                         prox_every=3, batch_size=batch_size)
+    batch_cfg = one_cfg._replace(event_batch=3)
     w0 = jnp.zeros((_D, _T), jnp.float32)
     key = jax.random.PRNGKey(seed)
-    d_st = amtl_events_only(problem, delta_cfg, w0, key, 12)
+    d_st = amtl_events_only(problem, one_cfg, w0, key, 12)
     b_st = amtl_events_only(problem, batch_cfg, w0, key, 12)
     np.testing.assert_array_equal(np.asarray(d_st.v), np.asarray(b_st.v))
     np.testing.assert_array_equal(np.asarray(d_st.key), np.asarray(b_st.key))
@@ -431,12 +433,13 @@ def test_masked_grad_matches_trimmed_dense_grad(seed, n, n_t_raw):
 
 @st.composite
 def _ragged_stream_setups(draw):
-    engine = draw(st.sampled_from(["delta", "batch", "sharded"]))
+    engine, eb = draw(st.sampled_from([("batch", 1), ("batch", 2),
+                                       ("sharded", 2)]))
     counts = draw(st.lists(st.integers(0, _N), min_size=_T, max_size=_T))
     batch_size = draw(st.one_of(st.none(), st.integers(1, _N)))
     split = draw(st.integers(0, 3))
     seed = draw(st.integers(0, 2**31 - 1))
-    return engine, counts, batch_size, split, seed
+    return engine, eb, counts, batch_size, split, seed
 
 
 @settings(max_examples=15, deadline=None)
@@ -446,10 +449,9 @@ def test_row_counts_and_appends_leave_event_stream_untouched(setup):
     against a grown buffer — must not perturb the PRNG chain head or the
     (task, staleness) history: activation sampling is data-independent,
     so every staleness/shard-invariance contract survives raggedness."""
-    engine, counts, batch_size, split, seed = setup
+    engine, eb, counts, batch_size, split, seed = setup
     problem = _tiny_problem()
     ragged = problem._replace(row_counts=jnp.asarray(counts, jnp.int32))
-    eb = 2 if engine in ("batch", "sharded") else 1
     cfg = AMTLConfig(eta=1.0 / problem.lipschitz(), eta_k=0.6, tau=2,
                      engine=engine, event_batch=eb, prox_every=2,
                      batch_size=batch_size)
@@ -487,7 +489,7 @@ def test_minibatching_leaves_event_stream_untouched(seed, batch_size):
     contract — is identical to the full-gradient run's."""
     problem = _tiny_problem()
     eta = 1.0 / problem.lipschitz()
-    full_cfg = AMTLConfig(eta=eta, eta_k=0.6, tau=2, engine="delta",
+    full_cfg = AMTLConfig(eta=eta, eta_k=0.6, tau=2, engine="batch",
                           prox_every=2)
     sgd_cfg = full_cfg._replace(batch_size=batch_size)
     w0 = jnp.zeros((_D, _T), jnp.float32)

@@ -23,11 +23,14 @@ from repro.core import AMTLConfig, make_engine
 from repro.launch.mesh import make_task_mesh
 from repro.serve import AMTLServer, ServeConfig
 
-ENGINES = ("dense", "delta", "batch", "sharded")
+ENGINES = ("dense", "batch1", "batch", "sharded")
 
 
 def _cfg(problem, engine, tau=3, **kw):
     eta = 1.0 / problem.lipschitz()
+    if engine == "batch1":              # the batch engine, one event a step
+        engine = "batch"
+        kw.setdefault("event_batch", 1)
     if engine in ("batch", "sharded"):
         kw.setdefault("event_batch", 4)
         kw.setdefault("prox_every", kw["event_batch"])
@@ -130,7 +133,7 @@ def test_serving_buffer_swaps_only_at_chunk_boundaries(small_problem, mesh1):
     """A request batch's predictions come off the buffer committed at the
     PREVIOUS boundary: feedback in batch k moves predictions from batch
     k+1 on, never batch k's."""
-    server = _server(small_problem, _cfg(small_problem, "delta"), mesh1,
+    server = _server(small_problem, _cfg(small_problem, "batch1"), mesh1,
                      ServeConfig(chunk_events=4))
     t, x = _requests(small_problem, 4)
     before = np.asarray(server.predict(t, x))
@@ -197,7 +200,7 @@ def test_resume_with_empty_dir_is_fresh_init(small_problem, mesh1,
                                              tmp_path):
     serve_cfg = ServeConfig(chunk_events=4, ckpt_dir=str(tmp_path))
     server = AMTLServer.resume(
-        small_problem, _cfg(small_problem, "delta"),
+        small_problem, _cfg(small_problem, "batch1"),
         jnp.zeros((small_problem.dim, small_problem.num_tasks), jnp.float32),
         jax.random.PRNGKey(0), serve_cfg)
     assert server.event_count == 0
@@ -205,7 +208,7 @@ def test_resume_with_empty_dir_is_fresh_init(small_problem, mesh1,
 
 # ------------------------------------------------------- admission / QoS
 def test_admission_cap_rejects_burst(small_problem, mesh1):
-    server = _server(small_problem, _cfg(small_problem, "delta"), mesh1,
+    server = _server(small_problem, _cfg(small_problem, "batch1"), mesh1,
                      ServeConfig(chunk_events=4, max_pending_per_task=3))
     receipt = server.submit_feedback([0] * 10)
     assert receipt == (3, 7)
@@ -217,7 +220,7 @@ def test_chunk_quota_stops_bursty_task_starving_budget(small_problem,
                                                        mesh1):
     """Task 0 floods the queue; the per-chunk quota keeps every other
     task's feedback flowing within the same chunk."""
-    server = _server(small_problem, _cfg(small_problem, "delta"), mesh1,
+    server = _server(small_problem, _cfg(small_problem, "batch1"), mesh1,
                      ServeConfig(chunk_events=6, task_chunk_quota=2))
     server.submit_feedback([0] * 50)
     server.submit_feedback([1, 2, 3, 4])
@@ -252,7 +255,7 @@ def test_resume_restores_mixed_padding_checkpoint(small_problem, mesh1,
     deliberately tolerates."""
     import os
     serve_cfg = ServeConfig(chunk_events=4, ckpt_dir=str(tmp_path))
-    server = _server(small_problem, _cfg(small_problem, "delta"), mesh1,
+    server = _server(small_problem, _cfg(small_problem, "batch1"), mesh1,
                      serve_cfg)
     server.submit_feedback([0, 1, 2, 3])
     server.step()
@@ -261,7 +264,7 @@ def test_resume_restores_mixed_padding_checkpoint(small_problem, mesh1,
     want = np.asarray(server.iterate())
     del server
     resumed = AMTLServer.resume(
-        small_problem, _cfg(small_problem, "delta"),
+        small_problem, _cfg(small_problem, "batch1"),
         jnp.zeros((small_problem.dim, small_problem.num_tasks), jnp.float32),
         jax.random.PRNGKey(0), serve_cfg)
     assert resumed.event_count == 4
@@ -276,7 +279,7 @@ def test_resume_builds_init_state_once(small_problem, mesh1, tmp_path,
     materializes a snapshot."""
     import repro.serve.server as srv_mod
     serve_cfg = ServeConfig(chunk_events=4, ckpt_dir=str(tmp_path))
-    server = _server(small_problem, _cfg(small_problem, "delta"), mesh1,
+    server = _server(small_problem, _cfg(small_problem, "batch1"), mesh1,
                      serve_cfg)
     server.submit_feedback([0, 1, 2])
     server.step()
@@ -297,7 +300,7 @@ def test_resume_builds_init_state_once(small_problem, mesh1, tmp_path,
 
     monkeypatch.setattr(srv_mod, "make_engine", spying_make_engine)
     resumed = AMTLServer.resume(
-        small_problem, _cfg(small_problem, "delta"),
+        small_problem, _cfg(small_problem, "batch1"),
         jnp.zeros((small_problem.dim, small_problem.num_tasks), jnp.float32),
         jax.random.PRNGKey(0), serve_cfg)
     assert len(init_calls) == 1
@@ -313,7 +316,7 @@ def test_predict_empty_batch_returns_empty_scores(small_problem, mesh1,
     ValueError.  An empty request batch is a valid request: it returns
     an empty (0,) score array in the link's dtype."""
     problem = small_problem._replace(loss_name=loss_name)
-    server = _server(problem, _cfg(problem, "delta"), mesh1)
+    server = _server(problem, _cfg(problem, "batch1"), mesh1)
     out = server.predict([], np.zeros((0, problem.dim), np.float32))
     assert out.shape == (0,)
     assert out.dtype == jnp.float32
@@ -327,7 +330,7 @@ def test_predict_empty_batch_returns_empty_scores(small_problem, mesh1,
 def test_predict_micro_batches_pad_and_slice(small_problem, mesh1):
     """Bucketed padding and max_batch slicing return exactly the
     unpadded scores in request order."""
-    server = _server(small_problem, _cfg(small_problem, "delta"), mesh1,
+    server = _server(small_problem, _cfg(small_problem, "batch1"), mesh1,
                      ServeConfig(chunk_events=4, max_batch=4))
     server.submit_feedback([0, 1, 2])
     server.step()
@@ -343,14 +346,14 @@ def test_predict_micro_batches_pad_and_slice(small_problem, mesh1):
 
 def test_logistic_predictions_are_probabilities(small_problem, mesh1):
     logit = small_problem._replace(loss_name="logistic")
-    server = _server(logit, _cfg(logit, "delta"), mesh1)
+    server = _server(logit, _cfg(logit, "batch1"), mesh1)
     t, x = _requests(logit, 6)
     p = np.asarray(server.predict(t, x))
     assert ((p > 0) & (p < 1)).all()
 
 
 def test_predict_validates_inputs(small_problem, mesh1):
-    server = _server(small_problem, _cfg(small_problem, "delta"), mesh1)
+    server = _server(small_problem, _cfg(small_problem, "batch1"), mesh1)
     with pytest.raises(ValueError, match="features must be"):
         server.predict([0, 1], np.zeros((2, 3), np.float32))
     with pytest.raises(ValueError, match="task_ids must be in"):
@@ -365,15 +368,15 @@ def test_serve_config_validates(small_problem, mesh1):
         _server(small_problem, _cfg(small_problem, "batch"), mesh1,
                 ServeConfig(chunk_events=6))
     with pytest.raises(ValueError, match="task_chunk_quota"):
-        _server(small_problem, _cfg(small_problem, "delta"), mesh1,
+        _server(small_problem, _cfg(small_problem, "batch1"), mesh1,
                 ServeConfig(chunk_events=4, task_chunk_quota=0))
     with pytest.raises(ValueError, match="nowhere to write"):
-        _server(small_problem, _cfg(small_problem, "delta"), mesh1,
+        _server(small_problem, _cfg(small_problem, "batch1"), mesh1,
                 ServeConfig(chunk_events=4, checkpoint_every=4))
 
 
 def test_stats_telemetry(small_problem, mesh1):
-    server = _server(small_problem, _cfg(small_problem, "delta"), mesh1,
+    server = _server(small_problem, _cfg(small_problem, "batch1"), mesh1,
                      ServeConfig(chunk_events=4))
     t, x = _requests(small_problem, 3)
     server.serve(t, x, feedback_task_ids=[0, 1])

@@ -14,7 +14,7 @@ contracts in tests/test_serve.py:
     submitted; a poisoned ITERATE is quarantined — state, snapshot,
     chunk log, and the boundary's folded rows all roll back bitwise;
   * resume: a corrupted newest checkpoint record falls back one
-    interval (all four engines, sharded under a degenerate 1-device
+    interval (every engine case, sharded under a degenerate 1-device
     mesh) and subsequent predictions are bitwise the uninterrupted
     server's at that boundary; a crash in the store/engine checkpoint
     split window leaves a resumable directory;
@@ -37,12 +37,15 @@ from repro.serve import (AMTLServer, BackgroundLearner, FaultPlan,
                          InjectedFault, ServeConfig, corrupt_leaf,
                          truncate_record)
 
-ENGINES = ("dense", "delta", "batch", "sharded")
-RAGGED_ENGINES = ("delta", "batch", "sharded")
+ENGINES = ("dense", "batch1", "batch", "sharded")
+RAGGED_ENGINES = ("batch1", "batch", "sharded")
 
 
 def _cfg(problem, engine, tau=3, **kw):
     eta = 1.0 / problem.lipschitz()
+    if engine == "batch1":              # the batch engine, one event a step
+        engine = "batch"
+        kw.setdefault("event_batch", 1)
     if engine in ("batch", "sharded"):
         kw.setdefault("event_batch", 4)
         kw.setdefault("prox_every", kw["event_batch"])
@@ -121,7 +124,7 @@ def test_supervised_no_faults_is_bitwise_plain_learner(small_problem,
     """restart_limit set but nothing crashing: the supervised drain is
     bitwise the cooperative loop — supervision is pure scaffolding
     until a crash happens."""
-    cfg = _cfg(small_problem, "delta")
+    cfg = _cfg(small_problem, "batch1")
     fb = [np.arange(4) % small_problem.num_tasks for _ in range(3)]
 
     sup = _server(small_problem, cfg, mesh1,
@@ -351,7 +354,7 @@ def test_poisoned_chunk_never_reaches_checkpoint(small_problem, mesh1,
 @pytest.mark.parametrize("engine", ENGINES)
 def test_corrupt_newest_checkpoint_falls_back_one_interval(
         small_problem, mesh1, engine, tmp_path):
-    """The satellite contract, all four engines (sharded under a
+    """The satellite contract, every engine case (sharded under a
     degenerate 1-device mesh): bit rot on the newest record costs one
     checkpoint interval — resume lands on the previous boundary and
     subsequent predictions are bitwise an uninterrupted server's at
@@ -399,7 +402,7 @@ def test_resume_refuses_all_corrupt_directory(small_problem, mesh1,
                                               tmp_path):
     """Every engine record damaged: resume raises CheckpointCorruptError
     instead of silently restarting the session from scratch."""
-    cfg = _cfg(small_problem, "delta")
+    cfg = _cfg(small_problem, "batch1")
     serve_cfg = ServeConfig(chunk_events=4, ckpt_dir=str(tmp_path))
     server = _server(small_problem, cfg, mesh1, serve_cfg)
     server.submit_feedback([0, 1, 2, 3])

@@ -35,8 +35,11 @@ from repro.serve import (AMTLServer, LatencySLOController, ServeConfig,
                          degraded_budget)
 
 
-def _cfg(problem, engine="delta", tau=3, **kw):
+def _cfg(problem, engine="batch1", tau=3, **kw):
     eta = 1.0 / problem.lipschitz()
+    if engine == "batch1":              # the batch engine, one event a step
+        engine = "batch"
+        kw.setdefault("event_batch", 1)
     if engine in ("batch", "sharded"):
         kw.setdefault("event_batch", 4)
         kw.setdefault("prox_every", kw["event_batch"])
@@ -76,7 +79,7 @@ def test_no_torn_reads_under_concurrent_predict_load(small_problem):
     """4 predict threads hammer while the learner absorbs a feedback
     stream: every snapshot any thread ever observes must be bitwise a
     chunk-boundary iterate of the server's own chunk log."""
-    cfg = _cfg(small_problem, "delta")
+    cfg = _cfg(small_problem, "batch1")
     server = _server(small_problem, cfg, ServeConfig(chunk_events=4))
     t, x = _requests(small_problem, 8, seed=1)
     observed = [[] for _ in range(4)]
@@ -141,7 +144,7 @@ def test_threaded_final_state_replays_chunk_log(small_problem):
 
 
 # ------------------------------------------------------ drain == cooperative
-@pytest.mark.parametrize("engine", ("delta", "batch"))
+@pytest.mark.parametrize("engine", ("batch1", "batch"))
 def test_drain_then_join_equals_cooperative_loop_bitwise(small_problem,
                                                          engine):
     """Same queued feedback, no concurrent submissions: the drained
@@ -173,7 +176,7 @@ def test_drain_then_join_equals_cooperative_loop_bitwise(small_problem,
 def test_threaded_then_resume_matches_cooperative(small_problem, tmp_path):
     """Threaded phase -> drain -> checkpoint -> crash -> resume: the
     resumed server serves bitwise the cooperative reference."""
-    cfg = _cfg(small_problem, "delta")
+    cfg = _cfg(small_problem, "batch1")
     sc = ServeConfig(chunk_events=4, ckpt_dir=str(tmp_path), keep_last=2)
     fb = [i % small_problem.num_tasks for i in range(9)]
     a = _server(small_problem, cfg, sc, key=2)
@@ -207,7 +210,7 @@ def test_threaded_then_resume_matches_cooperative(small_problem, tmp_path):
 
 # ----------------------------------------------------------- learner lifecycle
 def test_cooperative_step_is_fenced_while_learner_runs(small_problem):
-    server = _server(small_problem, _cfg(small_problem, "delta"))
+    server = _server(small_problem, _cfg(small_problem, "batch1"))
     server.start_learner()
     with pytest.raises(RuntimeError, match="owns the chunk loop"):
         server.step()
@@ -219,7 +222,7 @@ def test_cooperative_step_is_fenced_while_learner_runs(small_problem):
 
 
 def test_learner_exception_surfaces_on_stop(small_problem):
-    server = _server(small_problem, _cfg(small_problem, "delta"))
+    server = _server(small_problem, _cfg(small_problem, "batch1"))
 
     def boom(state, offs, n):
         raise RuntimeError("engine exploded")
@@ -239,7 +242,7 @@ def test_learner_exception_surfaces_on_stop(small_problem):
 
 
 def test_frozen_server_refuses_learner(small_problem):
-    server = _server(small_problem, _cfg(small_problem, "delta"),
+    server = _server(small_problem, _cfg(small_problem, "batch1"),
                      ServeConfig(chunk_events=4, learning=False))
     with pytest.raises(RuntimeError, match="frozen"):
         server.start_learner()
@@ -251,7 +254,7 @@ def test_checkpoint_cadence_preserved_on_learner_thread(small_problem,
     runs on the background thread."""
     sc = ServeConfig(chunk_events=4, ckpt_dir=str(tmp_path),
                      checkpoint_every=4, keep_last=2)
-    server = _server(small_problem, _cfg(small_problem, "delta"), sc)
+    server = _server(small_problem, _cfg(small_problem, "batch1"), sc)
     server.submit_feedback([i % small_problem.num_tasks for i in range(16)])
     server.start_learner()
     server.stop_learner(drain=True)
@@ -263,7 +266,7 @@ def test_checkpoint_cadence_preserved_on_learner_thread(small_problem,
 def test_serve_leaves_chunks_to_running_learner(small_problem):
     """serve() never steps cooperatively while the learner owns the
     chunk loop — it reports 0 events and lets the thread absorb."""
-    server = _server(small_problem, _cfg(small_problem, "delta"))
+    server = _server(small_problem, _cfg(small_problem, "batch1"))
     t, x = _requests(small_problem, 4)
     server.start_learner()
     _, receipt, ran = server.serve(t, x, feedback_task_ids=[0, 1])
@@ -325,7 +328,7 @@ def test_server_degrades_chunk_budget_under_slo_violation(small_problem):
     """An impossible SLO (every predict violates) shrinks the coalesced
     chunk sizes; the decisions land in stats()["slo"]."""
     sc = ServeConfig(chunk_events=8, slo_ms=1e-6, slo_window=4)
-    server = _server(small_problem, _cfg(small_problem, "delta"), sc)
+    server = _server(small_problem, _cfg(small_problem, "batch1"), sc)
     t, x = _requests(small_problem, 4)
     for _ in range(12):                 # 3 windows, every sample violates
         server.predict(t, x)
@@ -337,7 +340,7 @@ def test_server_degrades_chunk_budget_under_slo_violation(small_problem):
     assert server.step() == 1           # degraded budget, not the base 8
     assert server.chunk_log == [1]
     # a healthy SLO would have coalesced the full budget
-    relaxed = _server(small_problem, _cfg(small_problem, "delta"),
+    relaxed = _server(small_problem, _cfg(small_problem, "batch1"),
                       ServeConfig(chunk_events=8, slo_ms=1e6, slo_window=4))
     relaxed.submit_feedback([0, 1, 2, 3, 4])
     assert relaxed.step() == 5
@@ -346,7 +349,7 @@ def test_server_degrades_chunk_budget_under_slo_violation(small_problem):
 def test_slo_shed_rejects_feedback_while_degraded(small_problem):
     sc = ServeConfig(chunk_events=8, slo_ms=1e-6, slo_window=2,
                      slo_shed=True)
-    server = _server(small_problem, _cfg(small_problem, "delta"), sc)
+    server = _server(small_problem, _cfg(small_problem, "batch1"), sc)
     assert server.submit_feedback([0, 1]).accepted == 2   # healthy: flows
     t, x = _requests(small_problem, 4)
     server.predict(t, x)
@@ -360,11 +363,11 @@ def test_slo_shed_rejects_feedback_while_degraded(small_problem):
 
 def test_slo_config_validates(small_problem):
     with pytest.raises(ValueError, match="slo_shed requires slo_ms"):
-        _server(small_problem, _cfg(small_problem, "delta"),
+        _server(small_problem, _cfg(small_problem, "batch1"),
                 ServeConfig(chunk_events=4, slo_shed=True))
     with pytest.raises(ValueError, match="slo_ms must be > 0"):
-        _server(small_problem, _cfg(small_problem, "delta"),
+        _server(small_problem, _cfg(small_problem, "batch1"),
                 ServeConfig(chunk_events=4, slo_ms=0.0))
     with pytest.raises(ValueError, match="slo_window must be >= 1"):
-        _server(small_problem, _cfg(small_problem, "delta"),
+        _server(small_problem, _cfg(small_problem, "batch1"),
                 ServeConfig(chunk_events=4, slo_ms=5.0, slo_window=0))

@@ -34,7 +34,7 @@ from repro.data import TaskStore, stack_ragged
 from repro.kernels import ops, ref
 from repro.serve import AMTLServer, ServeConfig
 
-RAGGED_ENGINES = ("delta", "batch", "sharded")
+RAGGED_ENGINES = ("batch1", "batch", "sharded")
 
 
 def _ragged_lists(sizes, d, seed=0):
@@ -53,6 +53,9 @@ def ragged_problem():
 
 def _cfg(problem, engine, **kw):
     eta = 1.0 / problem.lipschitz()
+    if engine == "batch1":              # the batch engine, one event a step
+        engine = "batch"
+        kw.setdefault("event_batch", 1)
     if engine in ("batch", "sharded"):
         kw.setdefault("event_batch", 4)
         kw.setdefault("prox_every", kw["event_batch"])
@@ -270,7 +273,7 @@ def test_row_counts_leave_event_stream_untouched(ragged_problem, batch_size):
     """Raggedness only reshapes gradients: the PRNG chain head and the
     (task, staleness) history are data-independent, so they must match
     the same problem with row_counts dropped."""
-    cfg = _cfg(ragged_problem, "delta", batch_size=batch_size)
+    cfg = _cfg(ragged_problem, "batch1", batch_size=batch_size)
     uniform = ragged_problem._replace(row_counts=None)
     w0 = jnp.zeros((ragged_problem.dim, ragged_problem.num_tasks),
                    jnp.float32)
@@ -286,7 +289,7 @@ def test_mid_session_append_continues_event_stream(ragged_problem):
     """Rebuilding the engine against a grown store mid-session continues
     the SAME activation stream: the chain state lives in the engine
     state, not the problem."""
-    cfg = _cfg(ragged_problem, "delta")
+    cfg = _cfg(ragged_problem, "batch1")
     store = TaskStore.from_problem(ragged_problem)
     eng1 = make_engine(store.problem(), cfg)
     w0 = jnp.zeros((ragged_problem.dim, ragged_problem.num_tasks),
@@ -308,10 +311,10 @@ def test_mid_session_append_continues_event_stream(ragged_problem):
 @pytest.mark.parametrize("engine", ("batch", "sharded"))
 @pytest.mark.parametrize("batch_size", (None, 3))
 def test_ragged_engines_agree_bitwise(ragged_problem, engine, batch_size):
-    """delta/batch/sharded on the same ragged problem replay the same
-    event stream and masked arithmetic — full state bitwise (the
+    """One event a step, batch and sharded on the same ragged problem
+    replay the same event stream and masked arithmetic — full state bitwise (the
     multi-shard boundary is the CI serving smoke at 8 fake devices)."""
-    base = _cfg(ragged_problem, "delta", batch_size=batch_size,
+    base = _cfg(ragged_problem, "batch1", batch_size=batch_size,
                 prox_every=4)
     other = base._replace(engine=engine, event_batch=4)
     mesh = _mesh1() if engine == "sharded" else None
@@ -329,7 +332,7 @@ SIM_T, SIM_D, SIM_TAU, SIM_EPOCHS = len(SIM_SIZES), 10, 3, 250
 
 
 def test_ragged_engine_tracks_trimmed_float64_simulator():
-    """The ragged delta engine's trajectory must track the float64
+    """The ragged engine's trajectory must track the float64
     event-driven reference run DIRECTLY on the per-task-trimmed ragged
     cohorts — the cross-validation that the masked math implements the
     paper's per-node objective, not an artifact of the padding."""
@@ -344,7 +347,7 @@ def test_ragged_engine_tracks_trimmed_float64_simulator():
                         eta_k=float(eta_k), tau=SIM_TAU, seed=0)
     sim_traj = np.asarray(sim.objectives)[SIM_T - 1::SIM_T]
 
-    cfg = AMTLConfig(eta=eta, eta_k=eta_k, tau=SIM_TAU, engine="delta")
+    cfg = AMTLConfig(eta=eta, eta_k=eta_k, tau=SIM_TAU, engine="batch")
     w0 = jnp.zeros((SIM_D, SIM_T), jnp.float32)
     res = amtl_solve(stacked, cfg, w0, jax.random.PRNGKey(0),
                      num_epochs=SIM_EPOCHS)
@@ -374,7 +377,7 @@ def _labeled_batch(problem, k, rng):
     return t, x, y
 
 
-@pytest.mark.parametrize("engine", ("delta", "batch"))
+@pytest.mark.parametrize("engine", ("batch1", "batch"))
 def test_labeled_feedback_replays_fold_run_sequence_bitwise(small_problem,
                                                             engine):
     """The acceptance contract: after any mix of labeled and label-free
@@ -419,7 +422,7 @@ def test_labeled_feedback_replays_fold_run_sequence_bitwise(small_problem,
 def test_label_free_path_never_creates_store(small_problem):
     """Satellite (a) regression: the PR-8 API (no features/labels) must
     stay bitwise PR-8 — same replay, no store, no problem rebuild."""
-    cfg = _cfg(small_problem, "delta")
+    cfg = _cfg(small_problem, "batch1")
     server = _server(small_problem, cfg, ServeConfig(chunk_events=4))
     prob_obj = server.problem
     eng_obj = server.engine
@@ -438,7 +441,7 @@ def test_label_free_path_never_creates_store(small_problem):
 
 
 def test_submit_feedback_validates_rows(small_problem):
-    server = _server(small_problem, _cfg(small_problem, "delta"),
+    server = _server(small_problem, _cfg(small_problem, "batch1"),
                      ServeConfig(chunk_events=4))
     with pytest.raises(ValueError, match="given together"):
         server.submit_feedback([0], features=np.zeros((1, small_problem.dim),
@@ -458,7 +461,7 @@ def test_submit_feedback_validates_rows(small_problem):
 def test_rejected_items_drop_their_rows(small_problem):
     """Admission caps apply to the item: a rejected item contributes
     neither an event nor a row."""
-    server = _server(small_problem, _cfg(small_problem, "delta"),
+    server = _server(small_problem, _cfg(small_problem, "batch1"),
                      ServeConfig(chunk_events=4, max_pending_per_task=3))
     rng = np.random.default_rng(18)
     x = rng.standard_normal((10, small_problem.dim)).astype(np.float32)
@@ -479,7 +482,7 @@ def test_feedback_rows_change_future_predictions(small_problem):
     """Appended rows reshape the gradients the next chunks use: two
     servers fed the same events, one with rows and one without, serve
     different predictions after the fold."""
-    cfg = _cfg(small_problem, "delta")
+    cfg = _cfg(small_problem, "batch1")
     a = _server(small_problem, cfg, ServeConfig(chunk_events=4))
     b = _server(small_problem, cfg, ServeConfig(chunk_events=4))
     rng = np.random.default_rng(19)
@@ -499,7 +502,7 @@ def test_resume_with_store_is_bitwise_invisible(small_problem, tmp_path):
     """Kill a server whose store grew past a capacity doubling; resume
     must restore store + engine state such that identical subsequent
     traffic produces bitwise identical predictions and states."""
-    cfg = _cfg(small_problem, "delta")
+    cfg = _cfg(small_problem, "batch1")
     serve_cfg = ServeConfig(chunk_events=4, ckpt_dir=str(tmp_path),
                             keep_last=2)
     a = _server(small_problem, cfg, serve_cfg, key=1)
@@ -544,7 +547,7 @@ def test_store_checkpoints_pair_with_engine_records(small_problem, tmp_path):
     """Once labeled rows fold, every checkpoint writes a store record at
     the same step under <ckpt_dir>/store/, rotated with the same
     keep_last; resume reads the paired record."""
-    cfg = _cfg(small_problem, "delta")
+    cfg = _cfg(small_problem, "batch1")
     serve_cfg = ServeConfig(chunk_events=4, ckpt_dir=str(tmp_path),
                             checkpoint_every=4, keep_last=2)
     server = _server(small_problem, cfg, serve_cfg)

@@ -174,10 +174,11 @@ def test_kernel_compiles_for_v5e(one_chip, name):
     _assert_pallas(lowered.compile())
 
 
-def _engine_args(sharding, d, t, n, *, ragged):
+def _engine_args(sharding, d, t, n, *, ragged, event_batch=EVENT_BATCH,
+                 prox_every=EVENT_BATCH):
     cfg = AMTLConfig(eta=0.1, eta_k=amtl_max_step(TAU, t), tau=TAU,
-                     engine="batch", event_batch=EVENT_BATCH,
-                     prox_every=EVENT_BATCH, prox_rank=PROX_RANK,
+                     engine="batch", event_batch=event_batch,
+                     prox_every=prox_every, prox_rank=PROX_RANK,
                      batch_size=BATCH_SIZE if n > BATCH_SIZE else None)
     state = jax.eval_shape(
         lambda v0, k: init_batch_state(cfg, v0, t, k),
@@ -190,18 +191,24 @@ def _engine_args(sharding, d, t, n, *, ragged):
     return problem, cfg, jax.tree.map(on_chip, state), _spec(sharding, (t,))
 
 
-@pytest.mark.parametrize("shape", ("engine", "gradient", "serve", "cell"))
+@pytest.mark.parametrize("shape", ("engine", "gradient", "serve", "cell",
+                                   "one_event"))
 def test_batch_engine_step_compiles_for_v5e(one_chip, monkeypatch, shape):
     """The whole `_run_events` program (randomized prox, sampled grads,
     batched column update) with the dispatch steered to Pallas in-test.
     Where it samples minibatches (all but `engine`), a batch's gradients
     are one Pallas call; `cell` is the benchmark cell's step (3400 ragged
-    writers of 512 rows at 784 features), which compiles in about 20 s."""
+    writers of 512 rows at 784 features), which compiles in about 20 s.
+    `one_event` is the default `event_batch=1` with a prox cadence of 4
+    events (a carried prox cache), at the gradient shape."""
     d, t, n = {"engine": (D, T, N), "gradient": (D_G, T_G, N_G),
-               "serve": (D_S, T_S, 2 * N_S), "cell": (D_C, T_C, N_C)}[shape]
+               "serve": (D_S, T_S, 2 * N_S), "cell": (D_C, T_C, N_C),
+               "one_event": (D_G, T_G, N_G)}[shape]
+    cadence = {"event_batch": 1, "prox_every": 4} if shape == "one_event" \
+        else {}
     monkeypatch.setattr(ops, "_on_tpu", lambda: True)
     problem, cfg, state, offs = _engine_args(
-        one_chip, d, t, n, ragged=shape in ("serve", "cell"))
+        one_chip, d, t, n, ragged=shape in ("serve", "cell"), **cadence)
     compiled = _run_events.lower(problem, cfg, state, offs,
                                  2 * EVENT_BATCH).compile()
     _assert_pallas(compiled)
